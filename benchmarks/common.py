@@ -1,7 +1,6 @@
 """Shared benchmark helpers: tiny models, timed step execution, sim engines."""
 from __future__ import annotations
 
-import time
 from typing import List, Sequence
 
 import jax
@@ -45,11 +44,7 @@ def timed_step(eng: PatchedServeEngine, reqs: List[Request],
             eng._prepare(r)
     for _ in range(warm):
         eng._denoise_step(reqs)
-    ts = []
-    for _ in range(iters):
-        t0 = time.perf_counter()
-        eng._denoise_step(reqs)
-        ts.append(time.perf_counter() - t0)
+    ts = [eng._timed_step(reqs)[1] for _ in range(iters)]
     return float(np.median(ts))
 
 

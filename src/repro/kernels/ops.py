@@ -1,7 +1,9 @@
 """jit'd public wrappers for the Pallas kernels.
 
-``interpret`` auto-selects: True off-TPU (validation mode, executes the kernel
-body with the Pallas interpreter), False on TPU (Mosaic compilation).
+``interpret`` auto-selects: False on TPU (Mosaic compilation), True
+elsewhere. Interpret mode runs the kernel body in the Pallas interpreter and
+exists for the CPU tests only; the chip path (``chip_smoke.py``) refuses to
+run without a TPU instead of falling back to it.
 """
 from __future__ import annotations
 

@@ -19,11 +19,11 @@ def ref_groupnorm_stitch(patches, neighbors, mean_c, rstd_c, scale, bias,
 
 
 def ref_attention(q, k, v, scale=None):
-    """q,k,v: (B, S, H, D) full bidirectional attention, fp32 softmax."""
+    """q,k,v: (B, H, S, D) full bidirectional attention, fp32 softmax."""
     D = q.shape[-1]
     sc = scale if scale is not None else D ** -0.5
-    s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
+    s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
                    k.astype(jnp.float32)) * sc
     p = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bhqk,bkhd->bqhd", p, v.astype(jnp.float32))
+    o = jnp.einsum("bhqk,bhkd->bhqd", p, v.astype(jnp.float32))
     return o.astype(q.dtype)

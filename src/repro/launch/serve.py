@@ -10,6 +10,7 @@ import numpy as np
 from repro.core.requests import poisson_workload
 from repro.core.scheduler import SchedulerConfig
 from repro.core.serving import EngineConfig, PatchedServeEngine
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import diffusion as dm
 
 
@@ -24,6 +25,7 @@ def main():
     ap.add_argument("--policy", default="slo", choices=["slo", "fcfs"])
     ap.add_argument("--clock", default="real", choices=["real", "sim"])
     args = ap.parse_args()
+    enable_compile_cache()
 
     mcfg = dm.DiffusionConfig(kind=args.model, width=32, levels=2,
                               blocks_per_level=1, n_heads=2, groups=4,

@@ -219,11 +219,14 @@ def _self_attn(cfg, csp: CSP, p, x: jax.Array) -> jax.Array:
         def attn(imgs, _):
             n, H, Wd, _ = imgs.shape
             t = imgs.reshape(n, H * Wd, C)
-            q = (t @ p["wq"]).reshape(n, H * Wd, cfg.n_heads, hd)
-            k = (t @ p["wk"]).reshape(n, H * Wd, cfg.n_heads, hd)
-            v = (t @ p["wv"]).reshape(n, H * Wd, cfg.n_heads, hd)
-            o = grouped_attention_kernel(q, k, v)
-            o = o.reshape(n, H * Wd, C) @ p["wo"]
+
+            def heads(w):                       # -> (n, heads, tokens, hd)
+                return (t @ w).reshape(n, H * Wd, cfg.n_heads,
+                                       hd).transpose(0, 2, 1, 3)
+
+            o = grouped_attention_kernel(heads(p["wq"]), heads(p["wk"]),
+                                         heads(p["wv"]))
+            o = o.transpose(0, 2, 1, 3).reshape(n, H * Wd, C) @ p["wo"]
             return o.reshape(n, H, Wd, C)
 
         return x + patched_ops.per_image_apply(csp, x, attn)

@@ -5,18 +5,7 @@ import sys
 import textwrap
 from pathlib import Path
 
-import jax
-import pytest
-
 REPO = Path(__file__).resolve().parents[1]
-
-#: ``jax.shard_map`` graduated from ``jax.experimental`` only in later JAX
-#: releases; on seed-equivalent environments (jax 0.4.x) the top-level name
-#: is absent and every shard_map-based path fails at call time. Skip those
-#: tests instead of letting ``pytest -x`` dead-stop the tier-1 gate here.
-requires_shard_map = pytest.mark.skipif(
-    not hasattr(jax, "shard_map"),
-    reason="jax.shard_map unavailable in this JAX version")
 
 
 def _run(code: str, devices: int = 4) -> str:
@@ -35,7 +24,6 @@ def _run(code: str, devices: int = 4) -> str:
     return out.stdout
 
 
-@requires_shard_map
 def test_pipeline_parallel_matches_sequential():
     out = _run("""
     import jax, jax.numpy as jnp, numpy as np
@@ -61,7 +49,6 @@ def test_pipeline_parallel_matches_sequential():
     assert "PP-OK" in out
 
 
-@requires_shard_map
 def test_quantized_psum_multi_device():
     out = _run("""
     import jax, jax.numpy as jnp, numpy as np
@@ -80,7 +67,6 @@ def test_quantized_psum_multi_device():
     assert "QPSUM-OK" in out
 
 
-@requires_shard_map
 def test_moe_shard_map_matches_local():
     """EP shard_map path == single-device local path (same routing)."""
     out = _run("""
@@ -113,7 +99,6 @@ def test_moe_shard_map_matches_local():
     assert "MOE-EP-OK" in out
 
 
-@requires_shard_map
 def test_moe_token_gather_decode_path():
     """2D-EP token-gather (decode) == local path."""
     out = _run("""

@@ -41,9 +41,9 @@ def test_groupnorm_stitch_sweep(res, C, G, dtype, exact):
 ])
 def test_patch_attention_sweep(B, S, H, D, dtype):
     rng = np.random.default_rng(1)
-    q = jnp.asarray(rng.normal(size=(B, S, H, D)), dtype)
-    k = jnp.asarray(rng.normal(size=(B, S, H, D)), dtype)
-    v = jnp.asarray(rng.normal(size=(B, S, H, D)), dtype)
+    q = jnp.asarray(rng.normal(size=(B, H, S, D)), dtype)
+    k = jnp.asarray(rng.normal(size=(B, H, S, D)), dtype)
+    v = jnp.asarray(rng.normal(size=(B, H, S, D)), dtype)
     got = patch_attention(q, k, v, interpret=True)
     want = ref.ref_attention(q, k, v)
     tol = 3e-2 if dtype == jnp.bfloat16 else 1e-4

@@ -2,7 +2,6 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
 from repro.optim import (adafactor_init, adafactor_update, adamw_init,
                          adamw_update)
@@ -67,8 +66,6 @@ def test_compression_error_feedback_unbiased():
                                atol=2e-3)
 
 
-@pytest.mark.skipif(not hasattr(jax, "shard_map"),
-                    reason="jax.shard_map unavailable in this JAX version")
 def test_quantized_psum_single_device():
     # axis of size 1: quantized psum == identity up to quantization noise
     mesh = jax.make_mesh((1,), ("d",))

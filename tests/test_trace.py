@@ -489,8 +489,11 @@ def test_null_tracer_is_inert():
     assert nt.enabled is False
     assert nt.submit(None) is None            # any method, any args
     assert nt.anything(1, 2, k=3) is None
+    # dunder probes (copy, pickle, numpy) must miss, not return the no-op;
+    # object itself defines __getstate__ since Python 3.11, so probe one
+    # that only __getattr__ could answer
     with pytest.raises(AttributeError):
-        nt.__getstate__()
+        nt.__deepcopy__
 
 
 def test_trace_config_validation():
