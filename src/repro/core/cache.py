@@ -79,7 +79,6 @@ class PatchCache:
         self.uid_to_slot: Dict[int, int] = {}
         self._free: List[int] = list(range(capacity - 1, -1, -1))
         self.update_input_on_reuse = update_input_on_reuse
-        self.stats = {"hits": 0, "computed": 0, "expired": 0}
 
     # ---------------- control plane (host) ----------------
 
@@ -103,7 +102,6 @@ class PatchCache:
                 is_new[j] = True
                 n_new += 1
             slots[j] = s
-        self.stats["expired"] += len(expired)
         return SyncResult(slots=slots, is_new=is_new,
                           n_common=len(uids) - n_new, n_new=n_new,
                           n_expired=len(expired))
@@ -138,9 +136,6 @@ class PatchCache:
                                        jnp.asarray(in_mask))
         self.store_out = _scatter_where(self.store_out, slots, y,
                                         jnp.asarray(computed))
-        n = int(np.sum(np.asarray(computed)))
-        self.stats["computed"] += n
-        self.stats["hits"] += len(sync.slots) - n
 
 
 def bucket_size(n: int, ladder: Sequence[int] = (0, 8, 16, 32, 64, 128, 256,

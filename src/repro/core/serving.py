@@ -47,6 +47,15 @@ from repro.models import diffusion as dm
 from repro.models import sampler as sampler_mod
 from repro.models import vae as vae_mod
 
+# Host spans the engine records while a profiler session is open
+# (``jax.profiler.TraceAnnotation``; outside a session they record nothing):
+# the iteration, Algorithm 1, CSP split, the sampler step, the cache hook's
+# own work, a block's dispatch (also on the plain path, in
+# ``diffusion.denoise_patched``), CSP merge, the wait on the step's latents,
+# and the decode.
+SPANS = ("ps.tick", "ps.schedule", "ps.csp_split", "ps.sampler", "ps.cache",
+         "ps.block", "ps.csp_merge", "ps.wait", "ps.decode")
+
 
 @dataclass
 class EngineConfig:
@@ -77,7 +86,13 @@ class Metrics:
     slo_met: int = 0
     latencies: List[float] = field(default_factory=list)
     step_latencies: List[float] = field(default_factory=list)
+    # _predict_step_latency of the batch each step ran; aligns with
+    # step_latencies
+    step_predicted: List[float] = field(default_factory=list)
     compute_savings: List[float] = field(default_factory=list)
+    # blocking waits on the device: device->host reads and
+    # block_until_ready, all through PatchedServeEngine._host_sync
+    host_syncs: int = 0
     # per-step (resolution concentration, step fraction, cache hit rate)
     # triples — the calibration feed for fit_cache_hit_model
     cache_samples: List[Tuple[float, float, float]] = field(
@@ -246,8 +261,15 @@ class PatchedServeEngine:
     def _postprocess(self, req: Request) -> None:
         if self.cfg.clock == "sim" and self.cfg.sim_synthetic:
             return
-        img = vae_mod.vae_decode(self.vae, req.latent[None])[0]
-        self.outputs[req.rid] = np.asarray(img)
+        with jax.profiler.TraceAnnotation("ps.decode"):
+            img = vae_mod.vae_decode(self.vae, req.latent[None])[0]
+            self.outputs[req.rid] = self._host_sync(np.asarray, img)
+
+    def _host_sync(self, read, x):
+        """``read(x)``, a call that blocks on the device (``np.asarray``,
+        ``jax.block_until_ready``), counted in ``Metrics.host_syncs``."""
+        self.metrics.host_syncs += 1
+        return read(x)
 
     # ---------------- cache plumbing ----------------
 
@@ -261,14 +283,15 @@ class PatchedServeEngine:
              for j in range(csp.total)], np.int64)
         savings = []
 
-        def hook(name, kind, fn, x):
+        def cached_block(name, kind, fn, x):
             key = f"{name}:{tuple(x.shape[1:])}"
             c = self.caches.get(key)
             if c is None:
                 c = cache_mod.PatchCache(self.cfg.cache_capacity)
                 self.caches[key] = c
             sync = c.sync(uids_per_patch.tolist())
-            mask = np.asarray(c.reuse_mask(x, sync, self.predictor))
+            mask = self._host_sync(np.asarray,
+                                   c.reuse_mask(x, sync, self.predictor))
             if mask.all():
                 y = c.cached_outputs(sync)
             else:
@@ -281,7 +304,8 @@ class PatchedServeEngine:
                         c.cached_inputs(sync).astype(x.dtype), x)
                 else:
                     x_in = x
-                y_full = fn(x_in)
+                with jax.profiler.TraceAnnotation("ps.block"):
+                    y_full = fn(x_in)
                 if mask.any():
                     y = jnp.where(
                         jnp.asarray(mask).reshape(
@@ -292,6 +316,10 @@ class PatchedServeEngine:
             c.update(sync, x, y, jnp.asarray(~mask))
             savings.append(float(mask.mean()))
             return y
+
+        def hook(name, kind, fn, x):
+            with jax.profiler.TraceAnnotation("ps.cache"):
+                return cached_block(name, kind, fn, x)
 
         return hook, savings
 
@@ -329,10 +357,16 @@ class PatchedServeEngine:
         """One engine iteration at clock time ``now``: admit via Algorithm 1,
         run one denoising step for the active batch, retire completions.
         The caller owns the clock and should advance it by ``events.dt``."""
+        with jax.profiler.TraceAnnotation("ps.tick"):
+            return self._tick(now)
+
+    def _tick(self, now: float) -> TickEvents:
         ev = TickEvents(now=now)
         m = self.metrics
 
-        admitted, dropped = self.scheduler.schedule(self.wait, self.active, now)
+        with jax.profiler.TraceAnnotation("ps.schedule"):
+            admitted, dropped = self.scheduler.schedule(self.wait,
+                                                        self.active, now)
         for r in dropped:
             self.wait.remove(r)
             r.state = "dropped"
@@ -383,6 +417,7 @@ class PatchedServeEngine:
         ev.dt = step_real if self.cfg.clock == "real" else step_pred
         ev.stepped = True
         m.step_latencies.append(ev.dt)
+        m.step_predicted.append(step_pred)
         end = ev.end
 
         # straggler mitigation: a step far over prediction triggers
@@ -494,7 +529,8 @@ class PatchedServeEngine:
         so the clock stops only once the new latents exist on the device."""
         t0 = time.perf_counter()
         savings = self._denoise_step(reqs)
-        jax.block_until_ready([r.latent for r in reqs])
+        with jax.profiler.TraceAnnotation("ps.wait"):
+            self._host_sync(jax.block_until_ready, [r.latent for r in reqs])
         return savings, time.perf_counter() - t0
 
     def _denoise_step(self, active: List[Request]) -> List[float]:
@@ -503,14 +539,15 @@ class PatchedServeEngine:
             for r in active:
                 r.steps_done += 1
             return []
-        # bucket-pad per resolution so XLA sees a bounded shape lattice
-        padded = list(active)
-        for res, c in zip(self.resolutions, self._counts(active)):
-            for j in range(self._bucket(c) - c):
-                padded.append(self._dummy(tuple(res), j))
-        csp, patches = split([r.latent for r in padded],
-                             patch=self.patch,
-                             req_ids=[r.rid for r in padded])
+        with jax.profiler.TraceAnnotation("ps.csp_split"):
+            # bucket-pad per resolution so XLA sees a bounded shape lattice
+            padded = list(active)
+            for res, c in zip(self.resolutions, self._counts(active)):
+                for j in range(self._bucket(c) - c):
+                    padded.append(self._dummy(tuple(res), j))
+            csp, patches = split([r.latent for r in padded],
+                                 patch=self.patch,
+                                 req_ids=[r.rid for r in padded])
         by_rid = {r.rid: r for r in padded}
         step_req = jnp.asarray([by_rid[int(rid)].steps_done
                                 for rid in csp.req_ids], jnp.int32)
@@ -527,11 +564,13 @@ class PatchedServeEngine:
             # virtual clock: skip device math, only cache bookkeeping savings
             new_patches = patches
         else:
-            new_patches = sampler_mod.sampler_step(
-                self.mcfg, self.params, csp, patches, step_req, total_steps,
-                text, block_hook=hook)
-        outs = merge_by_request(csp, new_patches)
-        for r in active:                # dummies' outputs are discarded
-            r.latent = outs[r.rid]
-            r.steps_done += 1
+            with jax.profiler.TraceAnnotation("ps.sampler"):
+                new_patches = sampler_mod.sampler_step(
+                    self.mcfg, self.params, csp, patches, step_req,
+                    total_steps, text, block_hook=hook)
+        with jax.profiler.TraceAnnotation("ps.csp_merge"):
+            outs = merge_by_request(csp, new_patches)
+            for r in active:            # dummies' outputs are discarded
+                r.latent = outs[r.rid]
+                r.steps_done += 1
         return savings

@@ -311,6 +311,13 @@ def block_plan(cfg: DiffusionConfig) -> List[Tuple[str, str, int]]:
     return plan
 
 
+def _plain_block(name, kind, fn, x):
+    """A block without a hook; its dispatch is the engine's ``ps.block``
+    span (``repro.core.serving.SPANS``)."""
+    with jax.profiler.TraceAnnotation("ps.block"):
+        return fn(x)
+
+
 def denoise_patched(cfg: DiffusionConfig, params, csp: CSP, patches: jax.Array,
                     t_req: jax.Array, text: jax.Array,
                     block_hook: Optional[Callable] = None) -> jax.Array:
@@ -325,7 +332,7 @@ def denoise_patched(cfg: DiffusionConfig, params, csp: CSP, patches: jax.Array,
     temb = temb @ params["temb_w2"] + params["temb_b2"]          # (R, t_dim)
     temb_p = temb[jnp.asarray(csp.patch_req)]                    # (P, t_dim)
 
-    run = block_hook or (lambda name, kind, fn, x: fn(x))
+    run = block_hook or _plain_block
 
     if cfg.kind == "dit":
         x = run("tok_in", "pixel",
