@@ -93,6 +93,10 @@ class Metrics:
     # blocking waits on the device: device->host reads and
     # block_until_ready, all through PatchedServeEngine._host_sync
     host_syncs: int = 0
+    # traces (compiles) of the model step's compiled segments
+    # (``diffusion.SEGMENT_TRACES``) during this engine's steps; it stops
+    # rising once every batch composition has been seen
+    block_traces: int = 0
     # per-step (resolution concentration, step fraction, cache hit rate)
     # triples — the calibration feed for fit_cache_hit_model
     cache_samples: List[Tuple[float, float, float]] = field(
@@ -564,10 +568,12 @@ class PatchedServeEngine:
             # virtual clock: skip device math, only cache bookkeeping savings
             new_patches = patches
         else:
+            traces = dm.segment_traces()
             with jax.profiler.TraceAnnotation("ps.sampler"):
                 new_patches = sampler_mod.sampler_step(
                     self.mcfg, self.params, csp, patches, step_req,
                     total_steps, text, block_hook=hook)
+            self.metrics.block_traces += dm.segment_traces() - traces
         with jax.profiler.TraceAnnotation("ps.csp_merge"):
             outs = merge_by_request(csp, new_patches)
             for r in active:            # dummies' outputs are discarded

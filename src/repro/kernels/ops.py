@@ -21,15 +21,11 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def fused_groupnorm_stitch(csp, patches: jax.Array, scale: jax.Array,
-                           bias: jax.Array, groups: int, eps: float = 1e-5,
-                           exact: bool = True, halo: int = 1) -> jax.Array:
-    """CSP-aware fused GroupNorm + edge stitch.
-
-    Phase 1 (cheap segment reduction): exact per-request stats; Phase 2 (the
-    Pallas kernel): normalize + halo in one pass. With exact=False the stats
-    are per-patch (the paper's approximation).
-    """
+def groupnorm_stats(csp, patches: jax.Array, groups: int, eps: float = 1e-5,
+                    exact: bool = True):
+    """Per-patch, per-channel (mean, rstd), each (P, C), for the kernel:
+    exact per-request statistics by a segment reduction, or with
+    exact=False each patch's own (the paper's approximation)."""
     from repro.core.patched_ops import csp_group_stats
     P, p, _, C = patches.shape
     G = groups
@@ -42,11 +38,30 @@ def fused_groupnorm_stitch(csp, patches: jax.Array, scale: jax.Array,
         mean_p = jnp.mean(x, axis=(1, 3))
         var_p = jnp.mean(jnp.square(x - mean_p[:, None, :, None]), axis=(1, 3))
     rstd_p = jax.lax.rsqrt(var_p + eps)
-    mean_c = jnp.repeat(mean_p, C // G, axis=-1)                   # (P, C)
-    rstd_c = jnp.repeat(rstd_p, C // G, axis=-1)
-    return groupnorm_stitch(patches, jnp.asarray(csp.neighbors, jnp.int32),
+    return (jnp.repeat(mean_p, C // G, axis=-1),                   # (P, C)
+            jnp.repeat(rstd_p, C // G, axis=-1))
+
+
+def groupnorm_stitch_kernel(patches: jax.Array, neighbors, mean_c: jax.Array,
+                            rstd_c: jax.Array, scale: jax.Array,
+                            bias: jax.Array, halo: int = 1) -> jax.Array:
+    """The Pallas kernel alone: normalize with the given stats + halo."""
+    return groupnorm_stitch(patches, jnp.asarray(neighbors, jnp.int32),
                             mean_c, rstd_c, scale, bias, halo=halo,
                             interpret=not _on_tpu())
+
+
+def fused_groupnorm_stitch(csp, patches: jax.Array, scale: jax.Array,
+                           bias: jax.Array, groups: int, eps: float = 1e-5,
+                           exact: bool = True, halo: int = 1) -> jax.Array:
+    """CSP-aware fused GroupNorm + edge stitch.
+
+    Phase 1 (cheap segment reduction, ``groupnorm_stats``): the stats;
+    Phase 2 (the Pallas kernel): normalize + halo in one pass.
+    """
+    mean_c, rstd_c = groupnorm_stats(csp, patches, groups, eps, exact)
+    return groupnorm_stitch_kernel(patches, csp.neighbors, mean_c, rstd_c,
+                                   scale, bias, halo)
 
 
 def grouped_attention_kernel(q: jax.Array, k: jax.Array, v: jax.Array,

@@ -21,6 +21,7 @@ Fig. 1): the timestep embedding is per-request and broadcast per patch via
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 import math
 from dataclasses import dataclass
@@ -28,9 +29,12 @@ from typing import Callable, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-from repro.core.csp import CSP
-from repro.core import patched_ops
+from repro.core import patched_ops, patching
+from repro.core.csp import CSP, build_csp
+from repro.core.stitcher import gather_halo
+from repro.kernels import ops as kernel_ops
 from repro.models.layers import ParamBuilder
 
 
@@ -158,37 +162,146 @@ def init_diffusion(cfg: DiffusionConfig, key: jax.Array):
 
 
 # ---------------------------------------------------------------------------
-# Patched block implementations
+# Compiled segments
 # ---------------------------------------------------------------------------
 
-def _gn_stitch(cfg: DiffusionConfig, csp: CSP, x: jax.Array, gp) -> jax.Array:
-    """GroupNorm + halo, fused kernel when enabled; returns (P,p+2,p+2,C)."""
-    if cfg.use_kernels:
-        from repro.kernels.ops import fused_groupnorm_stitch
-        return fused_groupnorm_stitch(csp, x, gp["scale"], gp["bias"],
-                                      cfg.groups, exact=cfg.exact_stats)
-    from repro.core.stitcher import gather_halo
-    n = patched_ops.patched_groupnorm(csp, x, gp["scale"], gp["bias"],
-                                      cfg.groups, exact=cfg.exact_stats)
-    return gather_halo(n, csp.neighbors)
+@dataclass(frozen=True)
+class Composition:
+    """The static structure of a CSP batch, the key its programs compile
+    under: the patch side and the requests' resolutions in CSP order (groups,
+    offsets and neighbors follow from them), seen at a UNet ``level``."""
+    patch: int
+    res: Tuple[Tuple[int, int], ...]
+    level: int = 0
+
+    @staticmethod
+    def of(csp: CSP) -> "Composition":
+        return Composition(int(csp.patch),
+                           tuple((int(h), int(w)) for h, w in csp.res))
+
+    def view(self, ix) -> CSP:
+        """The composition's CSP inside a trace: its layout is static, the
+        index arrays the math reads are the traced ``ix``. A UNet level has
+        the same grid and neighbors at 1/2**level the spatial size."""
+        f = 2 ** self.level
+        csp = build_csp([(h // f, w // f) for h, w in self.res],
+                        patch=self.patch // f)
+        return dataclasses.replace(csp, patch_req=ix["patch_req"],
+                                   res=ix["res"] // f,
+                                   neighbors=ix["neighbors"])
 
 
-def _res_block(cfg, csp: CSP, p, x: jax.Array, temb_p: jax.Array) -> jax.Array:
-    """x: (P, s, s, Cin); temb_p: (P, t_dim)."""
-    h = _gn_stitch(cfg, csp, x, p["gn1"])
-    h = jax.nn.silu(h)
+def csp_arrays(csp: CSP) -> dict:
+    """The index arrays of ``csp`` that the segments read, on the device."""
+    return jax.device_put({k: np.asarray(getattr(csp, k), np.int32)
+                           for k in ("patch_req", "res", "neighbors")})
+
+
+# Runs of each segment's Python body, i.e. its traces (compiles); the engine
+# reads the total into ``serving.Metrics.block_traces``. Process-wide, as
+# jit's own cache is.
+SEGMENT_TRACES: collections.Counter = collections.Counter()
+
+
+def segment_traces() -> int:
+    return sum(SEGMENT_TRACES.values())
+
+
+def _segment(fn):
+    """``fn(cfg, csp, *args)`` as one compiled program per (config,
+    composition), called as ``seg(cfg, comp, ix, *args)``. The config and
+    the composition are the static key; the CSP's index arrays and every
+    other argument, block weights included, are traced, so that blocks of
+    one shape share one program."""
+    def body(cfg, comp, ix, *args):
+        SEGMENT_TRACES[fn.__name__] += 1
+        return fn(cfg, comp.view(ix), *args)
+
+    body.__name__ = body.__qualname__ = fn.__name__
+    return jax.jit(body, static_argnums=(0, 1))
+
+
+# ---------------------------------------------------------------------------
+# Patched block implementations
+# ---------------------------------------------------------------------------
+# A block with a Pallas kernel runs as compiled segments with each kernel
+# call between them on the host, where the benchmark counts the calls; a
+# block without one is one segment.
+
+def _norm(cfg, csp: CSP, gp, x: jax.Array) -> jax.Array:
+    return patched_ops.patched_groupnorm(csp, x, gp["scale"], gp["bias"],
+                                         cfg.groups, exact=cfg.exact_stats)
+
+
+def _gn_halo(cfg, csp: CSP, gp, x: jax.Array) -> jax.Array:
+    """GroupNorm + halo on the jnp path: (P, p+2, p+2, C)."""
+    return gather_halo(_norm(cfg, csp, gp, x), csp.neighbors)
+
+
+def _stats(cfg, csp: CSP, x: jax.Array):
+    """Per-patch (mean, rstd) for the fused GroupNorm + halo kernel."""
+    return kernel_ops.groupnorm_stats(csp, x, cfg.groups,
+                                      exact=cfg.exact_stats)
+
+
+def _gn_stitch(ix, gp, x: jax.Array, stats) -> jax.Array:
+    """The fused GroupNorm + halo kernel: (P, p+2, p+2, C)."""
+    return kernel_ops.groupnorm_stitch_kernel(x, ix["neighbors"], *stats,
+                                              gp["scale"], gp["bias"])
+
+
+def _conv_valid(p, h: jax.Array) -> jax.Array:
+    return jax.lax.conv_general_dilated(
+        h, p["w"], (1, 1), "VALID",
+        dimension_numbers=("NHWC", "HWIO", "NHWC")) + p["b"]
+
+
+def _res_in(csp: CSP, p, h: jax.Array, temb_p: jax.Array) -> jax.Array:
+    """From the first GroupNorm's haloed output to the second's input."""
     h = patched_ops.patched_conv(csp, None, p["conv1"]["w"], p["conv1"]["b"],
-                                 haloed=h)
+                                 haloed=jax.nn.silu(h))
     ss = jax.nn.silu(temb_p) @ p["temb_w"] + p["temb_b"]         # (P, 2C)
     scale, shift = jnp.split(ss, 2, axis=-1)
-    h = h * (1 + scale[:, None, None, :]) + shift[:, None, None, :]
-    h = _gn_stitch(cfg, csp, h, p["gn2"])
-    h = jax.nn.silu(h)
+    return h * (1 + scale[:, None, None, :]) + shift[:, None, None, :]
+
+
+def _res_out(csp: CSP, p, x: jax.Array, h: jax.Array) -> jax.Array:
+    """From the second GroupNorm's haloed output to the block's output."""
     h = patched_ops.patched_conv(csp, None, p["conv2"]["w"], p["conv2"]["b"],
-                                 haloed=h)
+                                 haloed=jax.nn.silu(h))
     if "skip" in p:
         x = patched_ops.patched_conv(csp, x, p["skip"]["w"], p["skip"]["b"])
     return x + h
+
+
+@_segment
+def _res_block(cfg, csp: CSP, p, x: jax.Array,
+               temb_p: jax.Array) -> jax.Array:
+    """x: (P, s, s, Cin); temb_p: (P, t_dim). The jnp path, whole."""
+    h = _res_in(csp, p, _gn_halo(cfg, csp, p["gn1"], x), temb_p)
+    return _res_out(csp, p, x, _gn_halo(cfg, csp, p["gn2"], h))
+
+
+@_segment
+def _res_mid(cfg, csp: CSP, p, h: jax.Array, temb_p: jax.Array):
+    h = _res_in(csp, p, h, temb_p)
+    return h, _stats(cfg, csp, h)
+
+
+@_segment
+def _res_post(cfg, csp: CSP, p, x: jax.Array, h: jax.Array) -> jax.Array:
+    return _res_out(csp, p, x, h)
+
+
+_gn_stats = _segment(_stats)
+
+
+def _res(cfg, comp, ix, p, x: jax.Array, temb_p: jax.Array) -> jax.Array:
+    if not cfg.use_kernels:
+        return _res_block(cfg, comp, ix, p, x, temb_p)
+    h = _gn_stitch(ix, p["gn1"], x, _gn_stats(cfg, comp, ix, x))
+    h, stats = _res_mid(cfg, comp, ix, p, h, temb_p)
+    return _res_post(cfg, comp, ix, p, x, _gn_stitch(ix, p["gn2"], h, stats))
 
 
 def _cross_attn(csp: CSP, p, x: jax.Array, text: jax.Array,
@@ -209,72 +322,145 @@ def _cross_attn(csp: CSP, p, x: jax.Array, text: jax.Array,
     return x + o.reshape(P, s, s, C)
 
 
-def _self_attn(cfg, csp: CSP, p, x: jax.Array) -> jax.Array:
-    """Image-level self-attention via CSP resolution groups."""
-    C = x.shape[-1]
-    if cfg.use_kernels:
-        from repro.kernels.ops import grouped_attention_kernel
-        hd = C // cfg.n_heads
-
-        def attn(imgs, _):
-            n, H, Wd, _ = imgs.shape
-            t = imgs.reshape(n, H * Wd, C)
-
-            def heads(w):                       # -> (n, heads, tokens, hd)
-                return (t @ w).reshape(n, H * Wd, cfg.n_heads,
-                                       hd).transpose(0, 2, 1, 3)
-
-            o = grouped_attention_kernel(heads(p["wq"]), heads(p["wk"]),
-                                         heads(p["wv"]))
-            o = o.transpose(0, 2, 1, 3).reshape(n, H * Wd, C) @ p["wo"]
-            return o.reshape(n, H, Wd, C)
-
-        return x + patched_ops.per_image_apply(csp, x, attn)
-    return x + patched_ops.grouped_self_attention(
-        csp, x, p["wq"], p["wk"], p["wv"], p["wo"], cfg.n_heads)
+def _modulate(x: jax.Array, mod) -> jax.Array:
+    """adaLN's scale and shift (the DiT's ``mod``); None leaves x as is."""
+    if mod is None:
+        return x
+    sc, sh, _ = mod
+    return x * (1 + sc[:, None, None, :]) + sh[:, None, None, :]
 
 
-def _attn_block(cfg, csp: CSP, p, x: jax.Array, text: jax.Array) -> jax.Array:
-    P, s, _, C = x.shape
-    h = patched_ops.patched_groupnorm(csp, x, p["gn"]["scale"], p["gn"]["bias"],
-                                      cfg.groups, exact=cfg.exact_stats)
-    h = _self_attn(cfg, csp, p, h)
+def _attn_tail(cfg, csp: CSP, p, x: jax.Array, h: jax.Array, text, mod):
+    """After self-attention: cross-attention, the feed-forward with its
+    GroupNorm, and adaLN's gated residual around the block."""
+    P, s, _, C = h.shape
     h = _cross_attn(csp, p, h, text, cfg.n_heads)
-    hn = patched_ops.patched_groupnorm(csp, h, p["gn_ff"]["scale"],
-                                       p["gn_ff"]["bias"], cfg.groups,
-                                       exact=cfg.exact_stats)
+    hn = _norm(cfg, csp, p["gn_ff"], h)
     ff = jax.nn.gelu(hn.reshape(P, s * s, C) @ p["ff1"]) @ p["ff2"]
-    return h + ff.reshape(P, s, s, C)
+    h = h + ff.reshape(P, s, s, C)
+    if mod is None:
+        return h
+    return x + mod[2][:, None, None, :] * (h - x)
 
 
-def _downsample(csp: CSP, p, x: jax.Array) -> jax.Array:
+@_segment
+def _attn_block(cfg, csp: CSP, p, x: jax.Array, text: jax.Array, mod):
+    """A transformer block on the jnp path, whole. ``mod``: the DiT's adaLN
+    (scale, shift, gate) per patch, or None."""
+    h = _norm(cfg, csp, p["gn"], _modulate(x, mod))
+    h = h + patched_ops.grouped_self_attention(
+        csp, h, p["wq"], p["wk"], p["wv"], p["wo"], cfg.n_heads)
+    return _attn_tail(cfg, csp, p, x, h, text, mod)
+
+
+@_segment
+def _attn_pre(cfg, csp: CSP, p, x: jax.Array, mod):
+    """Up to the attention kernel: the normalized input, and each resolution
+    group's Q, K, V as (images, heads, tokens, head_dim)."""
+    h = _norm(cfg, csp, p["gn"], _modulate(x, mod))
+    C = h.shape[-1]
+    qkv = []
+    for g in range(csp.n_groups):
+        imgs = patching.group_images(csp, h, g)
+        n, H, W, _ = imgs.shape
+        t = imgs.reshape(n, H * W, C)
+        qkv.append(tuple(
+            (t @ p[w]).reshape(n, H * W, cfg.n_heads,
+                               C // cfg.n_heads).transpose(0, 2, 1, 3)
+            for w in ("wq", "wk", "wv")))
+    return h, qkv
+
+
+@_segment
+def _attn_post(cfg, csp: CSP, p, x: jax.Array, h: jax.Array, outs, text,
+               mod):
+    """From the attention kernel's outputs to the block's output."""
+    C = h.shape[-1]
+    parts = []
+    for g, o in enumerate(outs):
+        n, _, S, _ = o.shape
+        H, W = map(int, csp.group_res[g])
+        o = o.transpose(0, 2, 1, 3).reshape(n, S, C) @ p["wo"]
+        parts.append(patching.ungroup_images(csp, o.reshape(n, H, W, C), g))
+    h = h + jnp.concatenate(parts, axis=0)
+    return _attn_tail(cfg, csp, p, x, h, text, mod)
+
+
+def _attn(cfg, comp, ix, p, x: jax.Array, text: jax.Array,
+          mod=None) -> jax.Array:
+    if not cfg.use_kernels:
+        return _attn_block(cfg, comp, ix, p, x, text, mod)
+    h, qkv = _attn_pre(cfg, comp, ix, p, x, mod)
+    outs = [kernel_ops.grouped_attention_kernel(*t) for t in qkv]
+    return _attn_post(cfg, comp, ix, p, x, h, outs, text, mod)
+
+
+@_segment
+def _conv(cfg, csp: CSP, p, x: jax.Array) -> jax.Array:
+    """A kxk convolution over patches with halos."""
+    return patched_ops.patched_conv(csp, x, p["w"], p["b"])
+
+
+@_segment
+def _downsample(cfg, csp: CSP, p, x: jax.Array) -> jax.Array:
     """Stride-2 3x3 conv with halo: (P, s, s, C) -> (P, s/2, s/2, C').
 
     Matches image-level SAME stride-2 conv (XLA pads right/bottom only for
     even sizes): windows start on even global rows, so only the right/bottom
     halo participates — drop the left/top halo row+col.
     """
-    from repro.core.stitcher import gather_halo
     h = gather_halo(x, csp.neighbors)[:, 1:, 1:, :]
     return jax.lax.conv_general_dilated(
         h, p["w"], (2, 2), "VALID",
         dimension_numbers=("NHWC", "HWIO", "NHWC")) + p["b"]
 
 
-def _upsample(csp: CSP, p, x: jax.Array) -> jax.Array:
+@_segment
+def _upsample(cfg, csp: CSP, p, x: jax.Array) -> jax.Array:
     """Nearest x2 then 3x3 conv (halo at the upsampled scale)."""
-    P, s, _, C = x.shape
     up = jnp.repeat(jnp.repeat(x, 2, axis=1), 2, axis=2)
     return patched_ops.patched_conv(csp, up, p["w"], p["b"])
 
 
-def csp_at_level(csp: CSP, level: int) -> CSP:
-    """Same grid/neighbors, halved spatial dims per level."""
-    if level == 0:
-        return csp
-    f = 2 ** level
-    return dataclasses.replace(csp, patch=csp.patch // f, res=csp.res // f,
-                               group_res=csp.group_res // f)
+@_segment
+def _out_block(cfg, csp: CSP, p, x: jax.Array) -> jax.Array:
+    """The UNet's final GroupNorm, SiLU and conv on the jnp path, whole."""
+    h = _gn_halo(cfg, csp, p["out_norm"], x)
+    return _conv_valid(p["out_conv"], jax.nn.silu(h))
+
+
+@_segment
+def _out_conv(cfg, csp: CSP, p, h: jax.Array) -> jax.Array:
+    return _conv_valid(p, jax.nn.silu(h))
+
+
+def _out(cfg, comp, ix, p, x: jax.Array) -> jax.Array:
+    if not cfg.use_kernels:
+        return _out_block(cfg, comp, ix, p, x)
+    h = _gn_stitch(ix, p["out_norm"], x, _gn_stats(cfg, comp, ix, x))
+    return _out_conv(cfg, comp, ix, p["out_conv"], h)
+
+
+@_segment
+def _linear(cfg, csp: CSP, w: jax.Array, b: jax.Array,
+            x: jax.Array) -> jax.Array:
+    return x @ w + b
+
+
+_groupnorm = _segment(_norm)
+
+
+@_segment
+def _embed(cfg, csp: CSP, p, t_req: jax.Array):
+    """The timestep MLP per patch: (P, t_dim); for the DiT, adaLN's (scale,
+    shift, gate) per patch instead."""
+    temb = timestep_embedding(t_req, cfg.t_dim)
+    temb = jax.nn.silu(temb @ p["temb_w1"] + p["temb_b1"])
+    temb = temb @ p["temb_w2"] + p["temb_b2"]                    # (R, t_dim)
+    if cfg.kind == "dit":
+        mod = jax.nn.silu(temb) @ p["adaln_w"] + p["adaln_b"]
+        return tuple(jnp.split(mod[csp.patch_req], 3, axis=-1))
+    return temb[csp.patch_req]
 
 
 # ---------------------------------------------------------------------------
@@ -320,98 +506,61 @@ def _plain_block(name, kind, fn, x):
 
 def denoise_patched(cfg: DiffusionConfig, params, csp: CSP, patches: jax.Array,
                     t_req: jax.Array, text: jax.Array,
-                    block_hook: Optional[Callable] = None) -> jax.Array:
+                    block_hook: Optional[Callable] = None,
+                    ix: Optional[dict] = None) -> jax.Array:
     """One model evaluation on a CSP patch batch.
 
     t_req: (R,) timestep per request (mixed steps in one batch, Fig. 1);
     text: (R, n_text, d_text). block_hook(name, kind, fn, x) -> x lets the
-    cache manager interpose per block (None = plain execution).
+    cache manager interpose per block (None = plain execution). ix:
+    ``csp_arrays(csp)``, made here when not given.
     """
-    temb = timestep_embedding(t_req, cfg.t_dim)
-    temb = jax.nn.silu(temb @ params["temb_w1"] + params["temb_b1"])
-    temb = temb @ params["temb_w2"] + params["temb_b2"]          # (R, t_dim)
-    temb_p = temb[jnp.asarray(csp.patch_req)]                    # (P, t_dim)
-
+    comp = Composition.of(csp)
+    ix = csp_arrays(csp) if ix is None else ix
+    emb = _embed(cfg, comp, ix, {k: v for k, v in params.items()
+                                 if k.startswith(("temb_", "adaln_"))}, t_req)
     run = block_hook or _plain_block
 
+    def block(name, fn, x, *rest, level=0):
+        c = dataclasses.replace(comp, level=level)
+        return run(name, "context",
+                   lambda xx: fn(cfg, c, ix, params[name], xx, *rest), x)
+
     if cfg.kind == "dit":
-        x = run("tok_in", "pixel",
-                lambda xx: xx @ params["tok_in"] + params["tok_in_b"], patches)
-        mod = jax.nn.silu(temb) @ params["adaln_w"] + params["adaln_b"]
-        sc, sh, gate = jnp.split(mod[jnp.asarray(csp.patch_req)], 3, axis=-1)
+        x = run("tok_in", "pixel", lambda xx: _linear(
+            cfg, comp, ix, params["tok_in"], params["tok_in_b"], xx), patches)
         for i in range(cfg.dit_depth):
-            name = f"blk{i}"
-            p = params[name]
-
-            def blk(xx, p=p):
-                h = xx * (1 + sc[:, None, None, :]) + sh[:, None, None, :]
-                h = _attn_block(cfg, csp, p, h, text)
-                return xx + gate[:, None, None, :] * (h - xx)
-
-            x = run(name, "context", blk, x)
-        x = patched_ops.patched_groupnorm(
-            csp, x, params["out_norm"]["scale"], params["out_norm"]["bias"],
-            cfg.groups, exact=cfg.exact_stats)
-        return run("tok_out", "pixel",
-                   lambda xx: xx @ params["tok_out"] + params["tok_out_b"], x)
+            x = block(f"blk{i}", _attn, x, text, emb)
+        x = _groupnorm(cfg, comp, ix, params["out_norm"], x)
+        return run("tok_out", "pixel", lambda xx: _linear(
+            cfg, comp, ix, params["tok_out"], params["tok_out_b"], xx), x)
 
     # unet
-    x = run("stem", "context",
-            lambda xx: patched_ops.patched_conv(csp, xx, params["stem"]["w"],
-                                                params["stem"]["b"]), patches)
+    x = block("stem", _conv, patches)
     skips = []
-    level_csp = [csp_at_level(csp, lvl) for lvl in range(cfg.levels)]
     for lvl in range(cfg.levels):
-        cl = level_csp[lvl]
         for i in range(cfg.blocks_per_level):
-            x = run(f"down{lvl}_res{i}", "context",
-                    lambda xx, lvl=lvl, i=i: _res_block(
-                        cfg, level_csp[lvl], params[f"down{lvl}_res{i}"], xx, temb_p), x)
+            x = block(f"down{lvl}_res{i}", _res, x, emb, level=lvl)
             if lvl in cfg.attn_levels:
-                x = run(f"down{lvl}_attn{i}", "context",
-                        lambda xx, lvl=lvl, i=i: _attn_block(
-                            cfg, level_csp[lvl], params[f"down{lvl}_attn{i}"], xx,
-                            text), x)
+                x = block(f"down{lvl}_attn{i}", _attn, x, text, level=lvl)
         skips.append(x)
         if lvl + 1 < cfg.levels:
-            x = run(f"down{lvl}_ds", "context",
-                    lambda xx, lvl=lvl: _downsample(level_csp[lvl],
-                                                params[f"down{lvl}_ds"], xx), x)
+            x = block(f"down{lvl}_ds", _downsample, x, level=lvl)
     lm = cfg.levels - 1
-    x = run("mid_res1", "context",
-            lambda xx: _res_block(cfg, level_csp[lm], params["mid_res1"], xx,
-                                  temb_p), x)
-    x = run("mid_attn", "context",
-            lambda xx: _attn_block(cfg, level_csp[lm], params["mid_attn"], xx,
-                                   text), x)
-    x = run("mid_res2", "context",
-            lambda xx: _res_block(cfg, level_csp[lm], params["mid_res2"], xx,
-                                  temb_p), x)
+    x = block("mid_res1", _res, x, emb, level=lm)
+    x = block("mid_attn", _attn, x, text, level=lm)
+    x = block("mid_res2", _res, x, emb, level=lm)
     for lvl in reversed(range(cfg.levels)):
         if lvl + 1 < cfg.levels:
-            x = run(f"up{lvl}_us", "context",
-                    lambda xx, lvl=lvl: _upsample(level_csp[lvl],
-                                              params[f"up{lvl}_us"], xx), x)
+            x = block(f"up{lvl}_us", _upsample, x, level=lvl)
         for i in range(cfg.blocks_per_level):
             if i == 0:
                 x = jnp.concatenate([x, skips[lvl]], axis=-1)
-            x = run(f"up{lvl}_res{i}", "context",
-                    lambda xx, lvl=lvl, i=i: _res_block(
-                        cfg, level_csp[lvl], params[f"up{lvl}_res{i}"], xx, temb_p), x)
+            x = block(f"up{lvl}_res{i}", _res, x, emb, level=lvl)
             if lvl in cfg.attn_levels:
-                x = run(f"up{lvl}_attn{i}", "context",
-                        lambda xx, lvl=lvl, i=i: _attn_block(
-                            cfg, level_csp[lvl], params[f"up{lvl}_attn{i}"], xx,
-                            text), x)
-
-    def out_fn(xx):
-        h = _gn_stitch(cfg, csp, xx, params["out_norm"])
-        h = jax.nn.silu(h)
-        return jax.lax.conv_general_dilated(
-            h, params["out_conv"]["w"], (1, 1), "VALID",
-            dimension_numbers=("NHWC", "HWIO", "NHWC")) + params["out_conv"]["b"]
-
-    return run("out", "context", out_fn, x)
+                x = block(f"up{lvl}_attn{i}", _attn, x, text, level=lvl)
+    out = {k: params[k] for k in ("out_norm", "out_conv")}
+    return run("out", "context", lambda xx: _out(cfg, comp, ix, out, xx), x)
 
 
 def denoise_image(cfg: DiffusionConfig, params, imgs: jax.Array,
