@@ -2,8 +2,9 @@
 
 Whole images, one request at a time, in straightforward `jax.numpy`: no
 patches, no halo stitch, no kernels, no batching. It imports nothing of the
-program. It follows the model as the program defines it (repro's `unet` and
-`dit` kinds), the DDIM and flow-Euler samplers and the stub VAE decoder.
+program. Each model kind's backbone is its module's `forward`
+(`bench/models/<kind>.py`); this file holds what the kinds share: the DDIM
+and flow-Euler samplers, the patch cache's rule and the stub VAE decoder.
 
 The patch cache reuses a block's cached output for a patch whose input moved
 by less than `tau` (relative mean square) since that patch was last computed.
@@ -14,153 +15,14 @@ served side made; everywhere else it decides for itself.
 """
 from __future__ import annotations
 
-import math
 from functools import partial
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-GN_EPS = 1e-5
-
-
-# ---------------------------------------------------------------------------
-# Layers on whole images (1, H, W, C)
-# ---------------------------------------------------------------------------
-
-def group_norm(x, p, groups):
-    n, h, w, c = x.shape
-    g = x.reshape(n, h * w, groups, c // groups)
-    mean = jnp.mean(g, axis=(1, 3), keepdims=True)
-    var = jnp.mean(jnp.square(g - mean), axis=(1, 3), keepdims=True)
-    g = (g - mean) * jax.lax.rsqrt(var + GN_EPS)
-    return g.reshape(n, h, w, c) * p["scale"] + p["bias"]
-
-
-def conv(x, p, stride=1, padding="SAME"):
-    y = jax.lax.conv_general_dilated(
-        x, p["w"], (stride, stride), padding,
-        dimension_numbers=("NHWC", "HWIO", "NHWC"))
-    return y + p["b"]
-
-
-def silu(x):
-    return x * jax.nn.sigmoid(x)
-
-
-def gelu_tanh(x):
-    return 0.5 * x * (1 + jnp.tanh(math.sqrt(2 / math.pi)
-                                   * (x + 0.044715 * x ** 3)))
-
-
-def attention(q, k, v, heads):
-    """q (n, S, C), k/v (n, T, C) -> (n, S, C); softmax over T."""
-    n, s, c = q.shape
-    d = c // heads
-    q = q.reshape(n, s, heads, d)
-    k = k.reshape(n, -1, heads, d)
-    v = v.reshape(n, -1, heads, d)
-    w = jax.nn.softmax(jnp.einsum("nshd,nthd->nhst", q, k) / math.sqrt(d), -1)
-    return jnp.einsum("nhst,nthd->nshd", w, v).reshape(n, s, c)
-
-
-def timestep_embedding(t, dim):
-    half = dim // 2
-    freqs = jnp.exp(-math.log(10000.0) * jnp.arange(half) / half)
-    a = t.astype(jnp.float32)[:, None] * freqs[None]
-    return jnp.concatenate([jnp.cos(a), jnp.sin(a)], axis=-1)
-
-
-def res_block(cfg, p, x, temb):
-    h = conv(silu(group_norm(x, p["gn1"], cfg["groups"])), p["conv1"])
-    ss = silu(temb) @ p["temb_w"] + p["temb_b"]
-    scale, shift = jnp.split(ss, 2, axis=-1)
-    h = h * (1 + scale[:, None, None]) + shift[:, None, None]
-    h = conv(silu(group_norm(h, p["gn2"], cfg["groups"])), p["conv2"])
-    if "skip" in p:
-        x = jnp.einsum("nhwc,cd->nhwd", x, p["skip"]["w"][0, 0]) + p["skip"]["b"]
-    return x + h
-
-
-def attn_block(cfg, p, x, text):
-    """Normalise, self-attend over the whole image, cross-attend to the
-    prompt, feed forward. As in the program, the self-attention residual
-    starts from the normalised input."""
-    n, hh, ww, c = x.shape
-    heads = cfg["n_heads"]
-    h = group_norm(x, p["gn"], cfg["groups"]).reshape(n, hh * ww, c)
-    h = h + attention(h @ p["wq"], h @ p["wk"], h @ p["wv"], heads) @ p["wo"]
-    h = h + attention(h @ p["xq"], text @ p["xk"], text @ p["xv"],
-                      heads) @ p["xo"]
-    hn = group_norm(h.reshape(n, hh, ww, c), p["gn_ff"], cfg["groups"])
-    ff = gelu_tanh(hn.reshape(n, hh * ww, c) @ p["ff1"]) @ p["ff2"]
-    return (h + ff).reshape(n, hh, ww, c)
-
-
-# ---------------------------------------------------------------------------
-# Backbones; `run(name, fn, x)` interposes the patch cache at each block
-# ---------------------------------------------------------------------------
-
-def time_mlp(cfg, P, t, dtype):
-    temb = timestep_embedding(t, cfg["t_dim"]).astype(dtype)
-    temb = silu(temb @ P["temb_w1"] + P["temb_b1"])
-    return temb @ P["temb_w2"] + P["temb_b2"]
-
-
-def unet(cfg, P, x, t, text, run):
-    temb = time_mlp(cfg, P, t, x.dtype)
-    levels, blocks = cfg["levels"], cfg["blocks_per_level"]
-    attn = set(cfg["attn_levels"])
-    x = run("stem", lambda z: conv(z, P["stem"]), x)
-    skips = []
-    for lvl in range(levels):
-        for i in range(blocks):
-            name = f"down{lvl}_res{i}"
-            x = run(name, lambda z, n=name: res_block(cfg, P[n], z, temb), x)
-            if lvl in attn:
-                name = f"down{lvl}_attn{i}"
-                x = run(name, lambda z, n=name: attn_block(cfg, P[n], z, text), x)
-        skips.append(x)
-        if lvl + 1 < levels:
-            name = f"down{lvl}_ds"
-            # stride-2 SAME on an even size pads one row/col at the far end
-            x = run(name, lambda z, n=name: conv(
-                z, P[n], 2, ((0, 1), (0, 1))), x)
-    x = run("mid_res1", lambda z: res_block(cfg, P["mid_res1"], z, temb), x)
-    x = run("mid_attn", lambda z: attn_block(cfg, P["mid_attn"], z, text), x)
-    x = run("mid_res2", lambda z: res_block(cfg, P["mid_res2"], z, temb), x)
-    for lvl in reversed(range(levels)):
-        if lvl + 1 < levels:
-            name = f"up{lvl}_us"
-            x = run(name, lambda z, n=name: conv(
-                jnp.repeat(jnp.repeat(z, 2, axis=1), 2, axis=2), P[n]), x)
-        for i in range(blocks):
-            if i == 0:
-                x = jnp.concatenate([x, skips[lvl]], axis=-1)
-            name = f"up{lvl}_res{i}"
-            x = run(name, lambda z, n=name: res_block(cfg, P[n], z, temb), x)
-            if lvl in attn:
-                name = f"up{lvl}_attn{i}"
-                x = run(name, lambda z, n=name: attn_block(cfg, P[n], z, text), x)
-    return run("out", lambda z: conv(
-        silu(group_norm(z, P["out_norm"], cfg["groups"])), P["out_conv"]), x)
-
-
-def dit(cfg, P, x, t, text, run):
-    temb = time_mlp(cfg, P, t, x.dtype)
-    x = run("tok_in", lambda z: z @ P["tok_in"] + P["tok_in_b"], x)
-    mod = silu(temb) @ P["adaln_w"] + P["adaln_b"]
-    sc, sh, gate = (m[:, None, None] for m in jnp.split(mod, 3, axis=-1))
-    for i in range(cfg["dit_depth"]):
-        name = f"blk{i}"
-
-        def blk(z, n=name):
-            h = attn_block(cfg, P[n], z * (1 + sc) + sh, text)
-            return z + gate * (h - z)
-
-        x = run(name, blk, x)
-    x = group_norm(x, P["out_norm"], cfg["groups"])
-    return run("tok_out", lambda z: z @ P["tok_out"] + P["tok_out_b"], x)
+from bench.models import kind
+from bench.models._layers import conv, silu
 
 
 # ---------------------------------------------------------------------------
@@ -175,9 +37,17 @@ def ddim_tables(steps, T=1000):
     return (np.float32(ts), np.float32(ab[ts]), np.float32(ab_next))
 
 
+def _sampler(cfg):
+    sampler = kind(cfg["kind"]).SAMPLER
+    if sampler not in ("ddim", "flow"):
+        raise ValueError(f"model kind {cfg['kind']!r}: unknown sampler "
+                         f"{sampler!r}")
+    return sampler
+
+
 def sampler_update(cfg, steps, k, x, model_out):
     """x_{k+1} from x_k and the backbone's output, in float32."""
-    if cfg["kind"] == "dit":
+    if _sampler(cfg) == "flow":
         return x + (-1.0 / steps) * model_out       # flow: t 1 -> 0
     _, ab, ab_next = (jnp.asarray(a) for a in ddim_tables(steps))
     a, an = ab[k], ab_next[k]
@@ -186,7 +56,7 @@ def sampler_update(cfg, steps, k, x, model_out):
 
 
 def model_time(cfg, steps, k):
-    if cfg["kind"] == "dit":
+    if _sampler(cfg) == "flow":
         return (1.0 - k.astype(jnp.float32) / steps) * 1000.0
     ts, _, _ = ddim_tables(steps)
     return jnp.asarray(ts)[k]
@@ -243,7 +113,7 @@ def cached_step(cfg, steps, tau, band, params, x, text, k, stores, decided):
         disagree[name] = jnp.sum((decided[name] != own) & ~near & (k > 0))
         return y
 
-    fwd = unet if cfg["kind"] == "unet" else dit
+    fwd = kind(cfg["kind"]).forward
     t = model_time(cfg, steps, k)[None]
     out = fwd(cfg, params, x.astype(dtype), t, text.astype(dtype), run)
     x_next = sampler_update(cfg, steps, k, x, out.astype(jnp.float32))
@@ -261,7 +131,7 @@ def empty_stores(cfg, params, x):
                         jax.ShapeDtypeStruct(y.shape, dtype))
         return y
 
-    fwd = unet if cfg["kind"] == "unet" else dit
+    fwd = kind(cfg["kind"]).forward
     text = jax.ShapeDtypeStruct((1, cfg["n_text"], cfg["d_text"]), dtype)
     jax.eval_shape(lambda p, z, c: fwd(cfg, p, z.astype(dtype),
                                        jnp.zeros((1,)), c, run),

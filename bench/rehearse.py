@@ -20,25 +20,10 @@ sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
 def kernel_shapes(cfg, patches_total):
     """{kernel: {shape}} a batch of `patches_total` patches (4 requests per
-    resolution) calls, read off the configuration."""
-    m = cfg["model"]
-    res = cfg["resolutions"]
-    from bench.harness import gcd_patch
-    p = gcd_patch(res)
-    gn, attn = set(), set()
-    if m["kind"] == "unet":
-        for lvl in range(m["levels"]):
-            c = m["width"] * 2 ** lvl
-            for cc in {c, 2 * c}:
-                gn.add((patches_total, p >> lvl, p >> lvl, cc))
-            if lvl in m["attn_levels"]:
-                for h, w in res:
-                    attn.add((4, m["n_heads"], (h >> lvl) * (w >> lvl),
-                              c // m["n_heads"]))
-    else:
-        for h, w in res:
-            attn.add((4, m["n_heads"], h * w, m["width"] // m["n_heads"]))
-    return {"groupnorm_stitch": gn, "patch_attention": attn}
+    resolution) calls, read off the configuration by its model kind's
+    module (`bench/models/<kind>.py`)."""
+    from bench.models import kind
+    return kind(cfg["model"]["kind"]).kernel_shapes(cfg, patches_total)
 
 
 def main() -> int:

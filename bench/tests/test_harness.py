@@ -1,7 +1,7 @@
-"""A whole run of the harness on the CPU at a tiny size of each model kind,
-past the look for a chip: sound, with the control in the program's place,
-and with the timed path broken underneath in each way a one-chip serving
-cell can break."""
+"""A whole run of the harness on the CPU at a tiny size of each model kind
+(`data/tiny-<kind>.json`), past the look for a chip: sound, with the control
+in the program's place, and with the timed path broken underneath in each
+way a one-chip serving cell can break."""
 import io
 import json
 import time
@@ -17,7 +17,7 @@ DATA = Path(__file__).resolve().parent / "data"
 MIX = {"kind": "closed", "clients_per_resolution": 4, "start_spread": 1.0}
 CHIP = {"flops_bf16": 1e12, "hbm_bytes_per_s": 1e11}
 SEED = 2**31 + 77
-KINDS = ["tiny-unet", "tiny-dit"]
+KINDS = sorted(p.stem for p in DATA.glob("tiny-*.json"))
 
 
 def cfg(name):

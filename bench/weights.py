@@ -2,7 +2,8 @@
 benchmark on the device in one jitted call.
 
 The layout (nested dicts of arrays, by block name) is the one repro's
-`init_diffusion` and `init_vae` build; `bench/tests` checks that the two
+`init_diffusion` and `init_vae` build; each model kind's module gives its
+backbone's (`bench/models/<kind>.py`), and `bench/tests` checks that the two
 agree at a small size. The scales follow the same rule: normal with standard
 deviation 1/sqrt(fan in) unless stated, zero biases, unit norm scales.
 """
@@ -14,81 +15,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-
-def _conv(spec, path, k, cin, cout):
-    spec.append((f"{path}/w", (k, k, cin, cout), 1.0 / math.sqrt(k * k * cin)))
-    spec.append((f"{path}/b", (cout,), "zeros"))
-
-
-def _norm(spec, path, c):
-    spec.append((f"{path}/scale", (c,), "ones"))
-    spec.append((f"{path}/bias", (c,), "zeros"))
-
-
-def _res(spec, path, cin, cout, t_dim):
-    _norm(spec, f"{path}/gn1", cin)
-    _conv(spec, f"{path}/conv1", 3, cin, cout)
-    spec.append((f"{path}/temb_w", (t_dim, 2 * cout), None))
-    spec.append((f"{path}/temb_b", (2 * cout,), "zeros"))
-    _norm(spec, f"{path}/gn2", cout)
-    _conv(spec, f"{path}/conv2", 3, cout, cout)
-    if cin != cout:
-        _conv(spec, f"{path}/skip", 1, cin, cout)
-
-
-def _attn(spec, path, c, d_text):
-    _norm(spec, f"{path}/gn", c)
-    for n in ("wq", "wk", "wv", "wo", "xq"):
-        spec.append((f"{path}/{n}", (c, c), None))
-    spec.append((f"{path}/xk", (d_text, c), None))
-    spec.append((f"{path}/xv", (d_text, c), None))
-    spec.append((f"{path}/xo", (c, c), None))
-    _norm(spec, f"{path}/gn_ff", c)
-    spec.append((f"{path}/ff1", (c, 4 * c), None))
-    spec.append((f"{path}/ff2", (4 * c, c), None))
+from bench.models import kind
 
 
 def model_spec(m):
-    """[(path, shape, init)] for a model section of a configuration file.
-    init: a standard deviation, None for 1/sqrt(fan in), "zeros" or "ones"."""
-    spec = []
-    t, W, C0 = m["t_dim"], m["width"], m["latent_channels"]
-    spec += [("temb_w1", (t, t), None), ("temb_b1", (t,), "zeros"),
-             ("temb_w2", (t, t), None), ("temb_b2", (t,), "zeros")]
-    if m["kind"] == "dit":
-        spec += [("tok_in", (C0, W), None), ("tok_in_b", (W,), "zeros"),
-                 ("adaln_w", (t, 3 * W), 0.02), ("adaln_b", (3 * W,), "zeros")]
-        for i in range(m["dit_depth"]):
-            _attn(spec, f"blk{i}", W, m["d_text"])
-        _norm(spec, "out_norm", W)
-        spec += [("tok_out", (W, C0), 0.02), ("tok_out_b", (C0,), "zeros")]
-        return spec
-    levels = m["levels"]
-    chans = [W * 2 ** lvl for lvl in range(levels)]
-    _conv(spec, "stem", 3, C0, W)
-    for lvl in range(levels):
-        c = chans[lvl]
-        for i in range(m["blocks_per_level"]):
-            _res(spec, f"down{lvl}_res{i}", c, c, t)
-            if lvl in m["attn_levels"]:
-                _attn(spec, f"down{lvl}_attn{i}", c, m["d_text"])
-        if lvl + 1 < levels:
-            _conv(spec, f"down{lvl}_ds", 3, c, chans[lvl + 1])
-    cm = chans[-1]
-    _res(spec, "mid_res1", cm, cm, t)
-    _attn(spec, "mid_attn", cm, m["d_text"])
-    _res(spec, "mid_res2", cm, cm, t)
-    for lvl in reversed(range(levels)):
-        c = chans[lvl]
-        if lvl + 1 < levels:
-            _conv(spec, f"up{lvl}_us", 3, chans[lvl + 1], c)
-        for i in range(m["blocks_per_level"]):
-            _res(spec, f"up{lvl}_res{i}", 2 * c if i == 0 else c, c, t)
-            if lvl in m["attn_levels"]:
-                _attn(spec, f"up{lvl}_attn{i}", c, m["d_text"])
-    _norm(spec, "out_norm", W)
-    _conv(spec, "out_conv", 3, W, C0)
-    return spec
+    """[(path, shape, init)] for a model section of a configuration file,
+    from its kind's module (`bench/models/<kind>.py`). init: a standard
+    deviation, None for 1/sqrt(fan in), "zeros" or "ones"."""
+    return kind(m["kind"]).weight_spec(m)
 
 
 def vae_spec(latent_channels, width=32):
