@@ -64,8 +64,23 @@ def fused_groupnorm_stitch(csp, patches: jax.Array, scale: jax.Array,
                                    scale, bias, halo)
 
 
-def grouped_attention_kernel(q: jax.Array, k: jax.Array, v: jax.Array,
-                             block_q: int = 128, block_k: int = 128
+# matmul precisions at which XLA's own f32 dots on the TPU make one bfloat16
+# MXU pass; under any other ("high", "highest", "float32", ...) the kernel's
+# products keep the MXU's f32 format
+_ONE_PASS = (None, "default", "bfloat16", "fastest")
+
+
+def attention_mxu_dtype():
+    """The operand dtype of the attention kernel's products at the ambient
+    matmul precision: bfloat16 on the TPU where that precision asks for one
+    pass, as XLA's dots do; float32 otherwise, and always in interpret mode,
+    where XLA's CPU dots are exact."""
+    if _on_tpu() and jax.config.jax_default_matmul_precision in _ONE_PASS:
+        return jnp.bfloat16
+    return jnp.float32
+
+
+def grouped_attention_kernel(q: jax.Array, k: jax.Array, v: jax.Array
                              ) -> jax.Array:
-    return patch_attention(q, k, v, block_q=block_q, block_k=block_k,
+    return patch_attention(q, k, v, mxu_dtype=attention_mxu_dtype(),
                            interpret=not _on_tpu())

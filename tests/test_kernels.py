@@ -1,4 +1,7 @@
 """Pallas kernels vs pure-jnp oracles: shape/dtype sweeps (interpret mode)."""
+import contextlib
+
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -32,24 +35,91 @@ def test_groupnorm_stitch_sweep(res, C, G, dtype, exact):
                                rtol=tol, atol=tol)
 
 
-@pytest.mark.parametrize("B,S,H,D,dtype", [
-    (2, 100, 4, 32, jnp.float32),
-    (1, 256, 2, 64, jnp.float32),
-    (3, 65, 1, 16, jnp.float32),
-    (2, 128, 2, 32, jnp.bfloat16),
-    (1, 17, 3, 8, jnp.float32),
-])
-def test_patch_attention_sweep(B, S, H, D, dtype):
+F32, BF16 = jnp.float32, jnp.bfloat16
+# (B, S, H, D, input dtype, MXU operand dtype); S not a multiple of 128 pads
+# the keys, and S = 600 takes five 128-blocks, the last one masked
+ATTENTION_CASES = [
+    (2, 100, 4, 32, F32, F32),
+    (1, 256, 2, 64, F32, F32),
+    (3, 65, 1, 16, F32, F32),
+    (2, 128, 2, 32, BF16, F32),
+    (1, 17, 3, 8, F32, F32),
+    (1, 600, 2, 64, F32, F32),
+    (2, 100, 2, 72, F32, BF16),
+    (3, 65, 2, 64, F32, BF16),
+    (1, 17, 3, 72, F32, BF16),
+    (1, 600, 2, 64, F32, BF16),
+]
+
+
+def _attention_id(case):
+    B, S, H, D, dtype, mxu = case
+    tail = "" if mxu == F32 else f"-mxu_{jnp.dtype(mxu).name}"
+    return f"{B}-{S}-{H}-{D}-{jnp.dtype(dtype).name}{tail}"
+
+
+@pytest.mark.parametrize("B,S,H,D,dtype,mxu", ATTENTION_CASES,
+                         ids=[_attention_id(c) for c in ATTENTION_CASES])
+def test_patch_attention_sweep(B, S, H, D, dtype, mxu):
+    """The kernel against full-softmax attention. On the bfloat16 MXU path
+    the reference takes the same bfloat16-rounded q, k and v, so the scores
+    agree to f32 summation order; what is left is p's rounding to bfloat16
+    before p.v, a relative 2**-9 on each weight, so at most 2**-9 max|v|
+    (about 9e-3 for these normal draws): hence 1e-2."""
     rng = np.random.default_rng(1)
     q = jnp.asarray(rng.normal(size=(B, H, S, D)), dtype)
     k = jnp.asarray(rng.normal(size=(B, H, S, D)), dtype)
     v = jnp.asarray(rng.normal(size=(B, H, S, D)), dtype)
-    got = patch_attention(q, k, v, interpret=True)
-    want = ref.ref_attention(q, k, v)
-    tol = 3e-2 if dtype == jnp.bfloat16 else 1e-4
+    got = patch_attention(q, k, v, mxu_dtype=mxu, interpret=True)
+    assert got.dtype == q.dtype
+    want = ref.ref_attention(*(x.astype(mxu).astype(dtype) for x in (q, k, v)))
+    if mxu == BF16:
+        tol = 1e-2
+    else:
+        tol = 3e-2 if dtype == jnp.bfloat16 else 1e-4
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32),
                                rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("on_tpu,precision,want", [
+    (True, None, BF16),
+    (True, "default", BF16),
+    (True, "bfloat16", BF16),
+    (True, "highest", F32),
+    (True, "float32", F32),
+    (False, None, F32),
+    (False, "bfloat16", F32),
+])
+def test_attention_mxu_dtype(monkeypatch, on_tpu, precision, want):
+    """The kernel's products follow the ambient matmul precision on the TPU,
+    as XLA's f32 dots do, and stay f32 in interpret mode."""
+    seen = {}
+
+    def record(q, k, v, **kw):
+        seen.update(kw)
+        return q
+
+    monkeypatch.setattr(ops, "_on_tpu", lambda: on_tpu)
+    monkeypatch.setattr(ops, "patch_attention", record)
+    x = jnp.zeros((1, 1, 8, 8), jnp.float32)
+    ctx = (jax.default_matmul_precision(precision) if precision
+           else contextlib.nullcontext())
+    with ctx:
+        ops.grouped_attention_kernel(x, x, x)
+    assert seen == dict(mxu_dtype=want, interpret=not on_tpu)
+
+
+@pytest.mark.parametrize("S", [17, 100, 256, 576, 600, 1024, 2304, 4096])
+def test_patch_attention_block_sizes(S):
+    """A block is a multiple of 128 up to 512 that divides S padded to 128,
+    so no block pads the sequence further (2304 stays 2304, 576 640)."""
+    from repro.kernels.patch_attention import block_size
+    sp = -(-S // 128) * 128
+    b = block_size(S)
+    assert b % 128 == 0 and b <= 512 and sp % b == 0
+    assert -(-S // b) * b == sp
+    assert b == {2304: 384, 4096: 512, 576: 128}.get(S, b)
 
 
 def test_groupnorm_stitch_ref_against_kernel_ref():

@@ -3,7 +3,8 @@
 The chip's compiler is installed even where no chip is attached, so these
 tests lower each kernel with ``interpret=False`` for a described v5e device
 at the shapes the SDXL-lite preset serves (64/96/128 latents, GCD patch 32:
-29 patches for one request of each size) and check that Mosaic accepts it.
+29 patches for one request of each size), and the attention kernel also at
+the benchmark cells' own shapes, and check that Mosaic accepts it.
 Interpret-mode tests cannot see what Mosaic refuses (tiling, scoped VMEM).
 
 The topology is described inside a fixture, never at import time, so that
@@ -75,5 +76,18 @@ def test_groupnorm_stitch_compiles_for_v5e(one_chip, P, p, C):
 def test_patch_attention_compiles_for_v5e(one_chip, n, H, S, D):
     text = _compiled_text(
         lambda q, k, v: patch_attention(q, k, v, interpret=False),
+        one_chip, *[((n, H, S, D), jnp.float32)] * 3)
+    assert "tpu_custom_call" in text
+
+
+# (n, H, S, D) of the benchmark's cells on the default path (bfloat16 MXU
+# operands, blocks from the sequence): PixArt's 1024-px and 768-px groups
+# (16 heads of 72) and SDXL's 512-px group at its inner level (20 heads of 64)
+@pytest.mark.parametrize("n,H,S,D", [(4, 16, 4096, 72), (4, 16, 2304, 72),
+                                     (4, 20, 576, 64)])
+def test_patch_attention_bf16_compiles_for_v5e(one_chip, n, H, S, D):
+    text = _compiled_text(
+        lambda q, k, v: patch_attention(q, k, v, mxu_dtype=jnp.bfloat16,
+                                        interpret=False),
         one_chip, *[((n, H, S, D), jnp.float32)] * 3)
     assert "tpu_custom_call" in text
