@@ -91,10 +91,6 @@ events kept only for SLO misses/drops), or ``sample``. The SLO-violation
 attribution histogram is printed either way; feed ``trace.jsonl`` to
 ``scripts/trace_report.py`` for the offline view.
 
-``--perf-json PATH`` appends a sim-throughput record (event-loop
-iterations per wall second, per regime and total) to PATH — the nightly
-perf trajectory writes one ``BENCH_<date>.json`` per run.
-
 ``--cachetier`` adds the fleet patch-cache-tier axis (shared scenario
 ``simtools.CACHE_TIER``): repeat-heavy hybrid-resolution traffic whose
 dominant resolution flips between phases, every run priced under the same
@@ -742,30 +738,6 @@ def traced_run(trace_dir, mode, seed):
             "predictor": pred}
 
 
-def perf_summary(results, date=None):
-    """Fold sweep records into the sim-throughput trajectory record the
-    nightly job persists as ``BENCH_<date>.json``: per-regime and total
-    event-loop iterations per wall second."""
-    regimes = []
-    for r in results:
-        wall = r.get("wall_s", 0.0)
-        ev = r.get("sim_events", 0)
-        regimes.append({
-            "qps": r["qps"], "policy": r["policy"],
-            "n_replicas": r["n_replicas"], "wall_s": wall,
-            "sim_events": ev,
-            "events_per_s": round(ev / wall, 1) if wall else 0.0})
-    total_wall = sum(r["wall_s"] for r in regimes)
-    total_ev = sum(r["sim_events"] for r in regimes)
-    return {"kind": "cluster_sweep_perf",
-            "date": date or time.strftime("%Y-%m-%d"),
-            "total": {"wall_s": round(total_wall, 2),
-                      "sim_events": total_ev,
-                      "events_per_s": round(total_ev / total_wall, 1)
-                      if total_wall else 0.0},
-            "regimes": regimes}
-
-
 #: ``cachetier_trace`` runs counted as no-tier PR-4 baselines by the
 #: headline assert (cache_affinity and the tier runs are this PR's)
 CACHETIER_BASELINES = ("round_robin", "join_shortest_queue", "least_slack",
@@ -828,10 +800,6 @@ def main() -> None:
                     choices=("all", "violations", "sample"),
                     help="per-request event retention for --trace-dir "
                          "(spans/attribution always cover every request)")
-    ap.add_argument("--perf-json", default=None, metavar="PATH",
-                    help="write the sim-throughput trajectory record "
-                         "(events/s per regime + total) to PATH, e.g. "
-                         "BENCH_$(date +%%F).json")
     ap.add_argument("--out", default="benchmarks/cluster_results.json")
     ap.add_argument("--duration", type=float, default=30.0)
     ap.add_argument("--seed", type=int, default=1)
@@ -935,17 +903,6 @@ def main() -> None:
     Path(args.out).write_text(json.dumps(out, indent=1))
     print(f"# wrote {args.out} ({len(results)} sweep points, "
           f"{len(wins)} routing wins vs round_robin)", file=sys.stderr)
-    if args.perf_json:
-        perf = perf_summary(results)
-        if perf["total"]["sim_events"] <= 0 \
-                or perf["total"]["events_per_s"] <= 0:
-            raise SystemExit("perf trajectory recorded zero sim "
-                             "throughput — sim_events plumbing "
-                             "regression?")
-        Path(args.perf_json).write_text(json.dumps(perf, indent=1))
-        print(f"# wrote {args.perf_json} "
-              f"(total {perf['total']['events_per_s']} events/s over "
-              f"{len(perf['regimes'])} regimes)", file=sys.stderr)
     if not wins:
         raise SystemExit("no sweep point where SLO/resolution-aware "
                          "routing beat round_robin — policy regression?")

@@ -1,4 +1,5 @@
-"""Shared benchmark helpers: tiny models, timed step execution, sim engines."""
+"""Shared helpers: a tiny CPU engine for calibration scripts and the sim
+cluster the sweep and its tests drive."""
 from __future__ import annotations
 
 from typing import List, Sequence
@@ -6,14 +7,12 @@ from typing import List, Sequence
 import jax
 import numpy as np
 
-from repro.core.latency_model import analytic_step_latency
-from repro.core.requests import Request, poisson_workload
+from repro.core.requests import Request
 from repro.core.scheduler import SchedulerConfig
 from repro.core.serving import EngineConfig, PatchedServeEngine
 from repro.models import diffusion as dm
 
 RES = [(16, 16), (24, 24), (32, 32)]          # latent Low / Medium / High
-LABELS = {(16, 16): "L", (24, 24): "M", (32, 32): "H"}
 
 
 def tiny_model(kind="unet", use_kernels=False, exact=True):
@@ -36,62 +35,15 @@ def make_requests(counts: Sequence[int], steps=4, rid0=0) -> List[Request]:
     return reqs
 
 
-def timed_step(eng: PatchedServeEngine, reqs: List[Request],
-               warm: int = 1, iters: int = 3) -> float:
-    """Median warm per-step latency of one batch composition."""
-    for r in reqs:
-        if r.latent is None:
-            eng._prepare(r)
-    for _ in range(warm):
-        eng._denoise_step(reqs)
-    ts = [eng._timed_step(reqs)[1] for _ in range(iters)]
-    return float(np.median(ts))
-
-
 def real_engine(use_cache=False, policy="slo", same_res=False, tau=0.05,
                 kind="unet"):
     cfg, params = tiny_model(kind)
-    ecfg = EngineConfig(clock="real", use_cache=use_cache, cache_tau=tau,
+    ecfg = EngineConfig(use_cache=use_cache, cache_tau=tau,
                         cache_capacity=256,   # sized to the tiny workloads
                         scheduler=SchedulerConfig(policy=policy,
                                                   same_res_only=same_res))
     return PatchedServeEngine(cfg, params, ecfg,
                               dict.fromkeys(map(tuple, RES), 1.0), RES)
-
-
-def sim_engine(policy="slo", same_res=False, steps=10, latency_scale=1.0,
-               mixed_batching=True):
-    """Sim-clock engine. mixed_batching=False models a system that cannot
-    batch across resolutions at all (per-resolution latency additive)."""
-    cfg, params = tiny_model()
-    ecfg = EngineConfig(clock="sim",
-                        scheduler=SchedulerConfig(policy=policy,
-                                                  same_res_only=same_res))
-    eng = PatchedServeEngine(cfg, params, ecfg,
-                             dict.fromkeys(map(tuple, RES), 1.0), RES)
-    for res in eng.resolutions:
-        eng.sa[res] = analytic_step_latency(
-            [1 if r == res else 0 for r in eng.resolutions],
-            eng.patches_per_res) * steps * latency_scale
-    if not mixed_batching:
-        ppr = eng.patches_per_res
-
-        class _Seq:
-            def predict(self, f):
-                counts = f[:len(RES)]
-                return latency_scale * sum(
-                    analytic_step_latency(
-                        [c if i == j else 0 for j in range(len(RES))], ppr)
-                    for i, c in enumerate(counts) if c > 0)
-
-        eng.latency_model = _Seq()
-    return eng
-
-
-def workload(eng, qps, duration=40.0, slo_scale=5.0, steps=10, seed=0,
-             mix=None):
-    return poisson_workload(qps, duration, RES, slo_scale, eng.sa,
-                            steps=steps, seed=seed, mix=mix)
 
 
 def make_cluster(n_replicas=3, policy="round_robin", autoscaler=None,
@@ -100,8 +52,8 @@ def make_cluster(n_replicas=3, policy="round_robin", autoscaler=None,
                  failures=None, checkpoint=None, cache_tier=None,
                  trace=None, batcher=None, tiers=None, monitor=None):
     """Multi-replica sim cluster over the benchmark resolution ladder.
-    Engines are synthetic sim (no tensors) with the patch-aware latency
-    surrogate; pair with ``repro.cluster.simtools.cluster_workload`` so
+    Engines are accounting ``SimEngine``s (no tensors) with the
+    patch-aware latency surrogate; pair with ``repro.cluster.simtools.cluster_workload`` so
     SLOs use the same standalone normalizers. ``cache=True`` (or a
     ``CacheHitModel``) makes the surrogate cache-aware; ``initial_mix`` +
     ``repartition`` drive the workload-adaptive affinity path; ``failures``

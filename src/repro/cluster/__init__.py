@@ -42,9 +42,11 @@ Layers:
                    dominant latency component), EWMA+CUSUM changepoint
                    detection, Prometheus / JSONL exporters (zero-cost
                    when off);
-- ``simtools``   — patch-aware (optionally cache-aware) sim engine
-                   factories plus steady / phased-drift / ramp workload
-                   generators shared by tests, benchmarks and examples.
+- ``simtools``   — the accounting engine (``SimEngine``: the serving loop
+                   on a virtual clock, no tensors), its patch-aware
+                   (optionally cache-aware) factory, plus steady /
+                   phased-drift / ramp workload generators shared by
+                   tests, benchmarks and examples.
 
 Quick start::
 
@@ -82,7 +84,7 @@ from repro.cluster.trace import (COMPONENTS, NULL_TRACER, NullTracer,
                                  TraceConfig, Tracer)
 from repro.cluster.simtools import (BATCH_MIX, CACHE_TIER, CASCADE_MIX,
                                     DEFAULT_RES, FLASH_CROWD,
-                                    PatchAwareLatency, Scenario,
+                                    PatchAwareLatency, Scenario, SimEngine,
                                     batch_cluster_kwargs,
                                     batch_former_config, batch_mix_workload,
                                     cachetier_config, cachetier_mean_mix,
@@ -111,6 +113,7 @@ __all__ = [
     "POLICIES", "register_policy",
     "make_policy", "MixTracker", "mix_drift", "partition_resolutions",
     "allocate_replica_counts", "DEFAULT_RES", "PatchAwareLatency",
+    "SimEngine",
     "Scenario", "CACHE_TIER", "CASCADE_MIX", "FLASH_CROWD",
     "cachetier_config", "cachetier_mean_mix", "cachetier_workload",
     "cascade_fleet_cost",

@@ -1,5 +1,5 @@
-"""Cluster driver — interleaves many steppable ``PatchedServeEngine``s on
-one discrete-event sim clock.
+"""Cluster driver — interleaves many steppable ``SimEngine``s on one
+discrete-event sim clock.
 
 The driver owns global time. Per event it: (1) delivers Poisson arrivals to
 the router frontend, (2) finalizes drained retiring replicas, (3) lets the
@@ -93,9 +93,8 @@ effective cold start (``AutoscalerConfig.warm_boot_factor``) — the
 elastic controller and the cache tier composing is exactly the regime the
 ``--warmboot`` benchmark section asserts.
 
-Engines must be sim-clock (``EngineConfig.clock == "sim"``); for large
-sweeps build them with ``sim_synthetic=True`` (see
-``repro.cluster.simtools``).
+Engines must be sim-clock accounting engines
+(``repro.cluster.simtools.SimEngine``, built by ``sim_engine_factory``).
 """
 from __future__ import annotations
 
@@ -115,6 +114,7 @@ from repro.cluster.cachetier import (CacheTier, CacheTierConfig, TierClient,
 from repro.cluster.metrics import ClusterMetrics, ReplicaReport
 from repro.cluster.replica import (MODEL_TIERS, CheckpointConfig, ModelTier,
                                    Replica, tier_ladder)
+from repro.cluster.simtools import SimEngine
 from repro.cluster.router import (MixTracker, Router,
                                   allocate_replica_counts, make_policy,
                                   mix_drift, partition_resolutions)
@@ -562,7 +562,7 @@ class Cluster:
                cold: float, cause: str = "init",
                tier: Optional[ModelTier] = None) -> Replica:
         eng = self.make_engine(list(resolutions))
-        if eng.cfg.clock != "sim":
+        if not isinstance(eng, SimEngine):
             raise ValueError("cluster driver requires sim-clock engines")
         if tier is not None:
             # tier the engine's latency surrogate: every predicted AND

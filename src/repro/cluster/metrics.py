@@ -80,8 +80,7 @@ class ClusterMetrics:
     # asserts (min_hold_slack_s, deadline_overshoot_max). Empty dict when
     # no former is configured.
     batching: dict = field(default_factory=dict)
-    # driver event-loop iterations this run took — the sim-throughput
-    # denominator for the nightly perf trajectory (always recorded)
+    # event-loop iterations of Cluster.run (always recorded)
     sim_events: int = 0
     # tracing (ClusterConfig.trace): SLO-violation attribution histogram,
     # predictor calibration, and retained bus events. Empty when disabled.

@@ -1,4 +1,4 @@
-"""Replica — one steppable ``PatchedServeEngine`` plus the cluster-side
+"""Replica — one steppable engine (a ``ServingLoop``) plus the cluster-side
 state the router and autoscaler need: readiness (cold start), busy horizon,
 resolution coverage, and utilization accounting.
 
@@ -37,7 +37,7 @@ from dataclasses import dataclass
 from typing import Dict, List, Optional, Tuple
 
 from repro.core.requests import Request
-from repro.core.serving import Metrics, PatchedServeEngine, TickEvents
+from repro.core.serving import Metrics, ServingLoop, TickEvents
 from repro.cluster.trace import NULL_TRACER
 
 
@@ -134,7 +134,7 @@ class Replica:
     #: wiring and the disabled path costs one attribute load + branch)
     tracer = NULL_TRACER
 
-    def __init__(self, rid: int, engine: PatchedServeEngine,
+    def __init__(self, rid: int, engine: ServingLoop,
                  spawn_at: float = 0.0, cold_start: float = 0.0,
                  zone: int = 0,
                  checkpoint: Optional[CheckpointConfig] = None,
@@ -168,10 +168,10 @@ class Replica:
         self._metrics_hist: List[Metrics] = []
         # partial-progress checkpointing: rid -> (steps_done, latent) at the
         # last snapshot. The dict models durable storage — it outlives
-        # fail() on purpose, and it holds the latent itself (None in
-        # synthetic sims, the actual array on tensor paths) so a resumed
-        # request really continues from the snapshotted state instead of
-        # skipping denoise steps on fresh noise.
+        # fail() on purpose, and it holds the latent itself (None under the
+        # accounting SimEngine, the actual array on a device engine) so a
+        # resumed request really continues from the snapshotted state
+        # instead of skipping denoise steps on fresh noise.
         self.ckpt_cfg = checkpoint
         self._ckpt: Dict[int, tuple] = {}
         self.checkpoint_writes = 0            # per-request snapshots written
@@ -440,7 +440,7 @@ class Replica:
         return orphans
 
     # -- repartition migration --------------------------------------------
-    def switch_engine(self, engine: PatchedServeEngine, now: float,
+    def switch_engine(self, engine: ServingLoop, now: float,
                       switch_cost: float = 0.0) -> None:
         """Swap to an engine over a new affinity block. Only legal once the
         old engine is drained (in-flight work finished where it started).

@@ -1,7 +1,7 @@
 """Sim-clock engine factories for cluster experiments.
 
-Every replica gets a ``PatchedServeEngine`` in ``sim_synthetic`` mode (no
-tensors; a step is pure accounting) with a **patch-aware** latency surrogate
+Every replica gets a ``SimEngine`` (no tensors; a step is pure accounting on
+a virtual clock) with a **patch-aware** latency surrogate
 (``repro.core.latency_model.patch_aware_step_latency``): compute priced in
 latent pixels, overhead in patch count — so replicas built over an affinity
 block (larger GCD patch) are honestly faster, and replicas with different
@@ -13,6 +13,7 @@ thing fleet-wide regardless of how replicas are partitioned.
 """
 from __future__ import annotations
 
+import time
 import warnings
 from dataclasses import dataclass, field
 from typing import (Callable, Dict, Iterator, KeysView, List, Optional,
@@ -25,7 +26,8 @@ from repro.core.latency_model import (CacheHitModel, patch_aware_step_latency,
                                       resolution_concentration)
 from repro.core.requests import Request, poisson_workload
 from repro.core.scheduler import SchedulerConfig
-from repro.core.serving import EngineConfig, PatchedServeEngine
+from repro.core.serving import (EngineConfig, Metrics, ServingLoop,
+                                TickEvents)
 
 Resolution = Tuple[int, int]
 
@@ -497,7 +499,7 @@ CASCADE_MIX = Scenario(
 
 class PatchAwareLatency:
     """Adapter giving one engine's composition features to the patch-aware
-    surrogate (plugs into ``PatchedServeEngine.latency_model``).
+    surrogate (plugs into ``SimEngine.latency_model``).
 
     With a ``CacheHitModel`` attached the surrogate is also *cache-aware*:
     each step's predicted latency is discounted by the modeled patch-cache
@@ -611,38 +613,101 @@ def standalone_latencies(resolutions: Sequence[Resolution] = None,
         for r in res}
 
 
+class SimEngine(ServingLoop):
+    """The accounting engine: the serving loop on a virtual clock. A step
+    runs nothing — it advances every active request's ``steps_done`` and
+    lasts what ``latency_model`` predicts (a ``PatchAwareLatency``, or
+    ``analytic_step_latency`` when None) — so requests carry no tensors and
+    a step is never a straggler. A cache-aware surrogate's modeled hit rate
+    goes into ``Metrics.compute_savings``, one entry per step."""
+
+    latency_model: Optional[PatchAwareLatency]
+
+    def _prepare(self, req: Request) -> None:
+        pass
+
+    def _postprocess(self, req: Request) -> None:
+        pass
+
+    def _predict_step_latency(self, reqs: List[Request]) -> float:
+        if not reqs or self.latency_model is None:
+            return super()._predict_step_latency(reqs)
+        # cache-aware surrogates also need the requests' step state (reuse
+        # probability grows as denoising converges)
+        counts = [self._bucket(c) for c in self._counts(reqs)]
+        return max(self.latency_model.predict_batch(counts, reqs), 1e-5)
+
+    def _timed_step(self, reqs: List[Request]) -> Tuple[List[float], float]:
+        dt = self._predict_step_latency(reqs)
+        lm = self.latency_model
+        if lm is not None and lm.cache is not None:
+            # the hit rate of the batch just priced: concentration of the
+            # bucketed composition, step fraction before this step
+            comp = [self._bucket(c) for c in self._counts(reqs)]
+            frac = float(np.mean([r.steps_done / max(r.total_steps, 1)
+                                  for r in reqs]))
+            self.metrics.compute_savings.append(lm.modeled_hit_rate(
+                resolution_concentration(comp, self.patches_per_res), frac))
+        for r in reqs:
+            r.steps_done += 1
+        return [], dt
+
+    def drain(self, now: float = 0.0,
+              max_wall: float = 1e9) -> Tuple[float, List[TickEvents]]:
+        """Tick until both queues are empty (or no progress is possible),
+        advancing the virtual clock by each step. Returns the clock time at
+        idle and the event trail."""
+        t0 = time.perf_counter()
+        events: List[TickEvents] = []
+        while self.has_work:
+            ev = self.tick(now)
+            events.append(ev)
+            now += ev.dt
+            if not (ev.stepped or ev.admitted or ev.dropped):
+                break                      # starved: nothing admissible
+            if time.perf_counter() - t0 > max_wall:
+                break
+        return now, events
+
+    def run(self, workload: List[Request], max_wall: float = 1e9) -> Metrics:
+        """Serve ``workload`` on the virtual clock: it jumps to the next
+        arrival when the engine is idle and to a step's end after it."""
+        pending = self._start_run(workload)
+        now = 0.0
+        t_start = time.perf_counter()
+        while pending or self.has_work:
+            if not self.has_work:
+                now = max(now, pending[0].arrival)
+            while pending and pending[0].arrival <= now:
+                self.submit(pending.pop(0))
+            ev = self.tick(now)
+            if ev.stepped:
+                now = ev.end
+            elif not self.active and pending:
+                now = pending[0].arrival
+            if time.perf_counter() - t_start > max_wall:
+                break
+        self.metrics.span = now
+        return self.metrics
+
+
 def sim_engine_factory(resolutions: Sequence[Resolution] = None,
                        steps: int = 10, scale: float = 1.0,
                        sched_policy: str = "slo",
-                       synthetic: bool = True,
-                       model_builder: Optional[Callable] = None,
                        cache: Optional[CacheHitModel] = None
-                       ) -> Callable[[Sequence[Resolution]],
-                                     PatchedServeEngine]:
+                       ) -> Callable[[Sequence[Resolution]], SimEngine]:
     """Returns ``factory(replica_resolutions) -> engine`` for
-    ``Cluster(engine_factory=...)``. One tiny diffusion model is shared by
-    every replica (sim engines never run it; synthetic mode skips tensors
-    entirely). Pass ``cache=CacheHitModel()`` for a cache-aware surrogate
-    (replica steps get faster with resolution concentration and step
-    fraction); SLO normalizers stay cache-free either way so deadlines mean
-    the same thing across configurations."""
+    ``Cluster(engine_factory=...)``. Pass ``cache=CacheHitModel()`` for a
+    cache-aware surrogate (replica steps get faster with resolution
+    concentration and step fraction); SLO normalizers stay cache-free either
+    way so deadlines mean the same thing across configurations."""
     fleet_res = [tuple(r) for r in (resolutions or DEFAULT_RES)]
     sa = standalone_latencies(fleet_res, steps=steps, scale=scale)
-    if model_builder is None:
-        from repro.models import diffusion as dm
-        import jax
-        mcfg = dm.DiffusionConfig(kind="unet", width=16, levels=2,
-                                  blocks_per_level=1, n_heads=2, groups=4,
-                                  d_text=8, n_text=2, use_kernels=False)
-        params = dm.init_diffusion(mcfg, jax.random.PRNGKey(0))
-    else:
-        mcfg, params = model_builder()
 
-    def factory(replica_res: Sequence[Resolution]) -> PatchedServeEngine:
+    def factory(replica_res: Sequence[Resolution]) -> SimEngine:
         res = [tuple(r) for r in replica_res]
-        ecfg = EngineConfig(clock="sim", sim_synthetic=synthetic,
-                            scheduler=SchedulerConfig(policy=sched_policy))
-        eng = PatchedServeEngine(mcfg, params, ecfg, dict(sa), res)
+        ecfg = EngineConfig(scheduler=SchedulerConfig(policy=sched_policy))
+        eng = SimEngine(ecfg, dict(sa), res)
         eng.latency_model = PatchAwareLatency(res, eng.patch, scale,
                                               cache=cache)
         return eng

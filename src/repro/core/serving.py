@@ -1,12 +1,10 @@
 """PatchedServe engine — request lifecycle, 3 stages, patch batching, cache,
 SLO scheduling (paper Fig. 2).
 
-Two clocks:
-- ``real``: actually executes the JAX diffusion model per step (tiny models,
-  CPU) and measures wall time — used by examples/tests;
-- ``sim``: virtual clock driven by a calibrated latency surrogate — used by
-  the QPS-sweep benchmarks (the paper's Fig. 12-15 analogues), since an H100
-  isn't available to replay the paper's absolute timings.
+``PatchedServeEngine`` runs the diffusion model on the device and times each
+step on the wall clock. Its request loop (``ServingLoop``: admission, step
+accounting, straggler rule, retirement) is shared with engines that price a
+step instead of running it, such as the cluster layer's accounting engine.
 
 Per engine iteration (continuous batching at step granularity, no
 preemption):
@@ -20,10 +18,10 @@ preemption):
   6. straggler mitigation: if a step ran > straggler_factor x predicted,
      re-estimate active requests and drop newly-hopeless ones.
 
-The engine is **steppable**: an external driver (``repro.cluster``) owns the
-clock and interleaves many engines by calling ``submit(req)`` and
-``tick(now)`` — one engine iteration that returns a ``TickEvents`` record —
-while ``run()`` is a thin single-engine wrapper around the same loop.
+The engine is **steppable**: an external driver owns the clock by calling
+``submit(req)`` and ``tick(now)`` — one engine iteration that returns a
+``TickEvents`` record — while ``run()`` is a thin single-engine wrapper around
+the same loop.
 """
 from __future__ import annotations
 
@@ -59,17 +57,12 @@ SPANS = ("ps.tick", "ps.schedule", "ps.csp_split", "ps.sampler", "ps.cache",
 
 @dataclass
 class EngineConfig:
-    clock: str = "real"                 # real | sim
+    clock: str = "real"                 # only "real" is accepted
     use_cache: bool = False
     cache_tau: float = 5e-3
     cache_capacity: int = 8192
     patch_cap: int = 0                  # 0 = pure GCD (paper default)
     straggler_factor: float = 3.0
-    # sim-clock only: skip latent/text allocation, patch split/merge and VAE
-    # decode entirely — requests carry no tensors and a step just advances
-    # steps_done. Makes large cluster sweeps cheap; latency accounting is
-    # identical (the predictor only sees batch compositions).
-    sim_synthetic: bool = False
     # Composition bucketing (docs/ARCHITECTURE.md §4): per-resolution counts
     # are padded up to this ladder with dummy requests so XLA compiles a
     # small bounded program set. The padding overhead is charged honestly to
@@ -128,13 +121,16 @@ class TickEvents:
         return self.now + self.dt
 
 
-class PatchedServeEngine:
-    def __init__(self, model_cfg: dm.DiffusionConfig, params,
-                 engine_cfg: EngineConfig,
+class ServingLoop:
+    """The steppable request loop: Algorithm-1 admission, one step for the
+    whole active batch, the straggler rule and retirement. A subclass gives
+    the stages — ``_prepare(req)``, ``_timed_step(reqs)`` (the step's cache
+    savings and its duration) and ``_postprocess(req)`` — and the clock that
+    ``drain`` and ``run`` advance."""
+
+    def __init__(self, engine_cfg: EngineConfig,
                  standalone_latency: Dict[Tuple[int, int], float],
                  resolutions: Sequence[Tuple[int, int]]):
-        self.mcfg = model_cfg
-        self.params = params
         self.cfg = engine_cfg
         self.resolutions = [tuple(r) for r in resolutions]
         self.sa = standalone_latency
@@ -145,13 +141,8 @@ class PatchedServeEngine:
         self.scheduler = Scheduler(engine_cfg.scheduler, base_patch,
                                    standalone_latency,
                                    self._predict_step_latency)
-        self.vae = vae_mod.init_vae(jax.random.PRNGKey(7),
-                                    model_cfg.latent_channels)
-        self.rng = np.random.default_rng(engine_cfg.seed)
-        self.caches: Dict[str, cache_mod.PatchCache] = {}
-        self.predictor = ThresholdPredictor(engine_cfg.cache_tau)
-        self._uid_base: Dict[int, int] = {}   # rid -> uid namespace
-        self.outputs: Dict[int, np.ndarray] = {}
+        # step-latency surrogate; None prices steps with analytic_step_latency
+        self.latency_model = None
         # steppable state (owned here so an external driver can interleave
         # many engines; run() resets metrics but keeps compile/shape caches)
         self.wait: List[Request] = []
@@ -176,15 +167,149 @@ class PatchedServeEngine:
             return 0.0
         # predict for the *bucketed* composition — what actually executes
         counts = [self._bucket(c) for c in self._counts(reqs)]
-        lm = getattr(self, "latency_model", None)
-        if lm is not None:
-            if hasattr(lm, "predict_batch"):
-                # cache-aware surrogates also need the requests' step state
-                # (reuse probability grows as denoising converges)
-                return max(lm.predict_batch(counts, reqs), 1e-5)
-            return max(lm.predict(
-                make_features(counts, self.patches_per_res)), 1e-5)
-        return analytic_step_latency(counts, self.patches_per_res)
+        if self.latency_model is None:
+            return analytic_step_latency(counts, self.patches_per_res)
+        return max(self.latency_model.predict(
+            make_features(counts, self.patches_per_res)), 1e-5)
+
+    # ---------------- steppable API ----------------
+
+    def submit(self, req: Request) -> None:
+        """Enqueue an arrived request; it is considered by Algorithm 1 on the
+        next ``tick``."""
+        self.wait.append(req)
+
+    @property
+    def has_work(self) -> bool:
+        return bool(self.wait or self.active)
+
+    @property
+    def queue_depth(self) -> int:
+        return len(self.wait) + len(self.active)
+
+    def backlog_estimate(self) -> float:
+        """Predicted seconds until this engine drains everything it holds,
+        assuming all of it batches together (upper-bounds composition; the
+        router only needs a comparable load signal, not an exact forecast)."""
+        reqs = self.active + self.wait
+        if not reqs:
+            return 0.0
+        step = self._predict_step_latency(reqs)
+        return step * max(r.remaining_steps for r in reqs)
+
+    def reset_metrics(self) -> None:
+        """Fresh Metrics; keeps compile/shape caches so warm engines stay
+        warm across runs."""
+        self.metrics = Metrics()
+
+    def _start_run(self, workload: List[Request]) -> List[Request]:
+        """Empty queues and fresh metrics for a self-contained ``run``: drops
+        anything a previous max_wall-truncated run (or external submit/tick
+        use) left queued. Returns the workload in arrival order."""
+        self.wait.clear()
+        self.active.clear()
+        self.reset_metrics()
+        return sorted(workload, key=lambda r: r.arrival)
+
+    def tick(self, now: float) -> TickEvents:
+        """One engine iteration at clock time ``now``: admit via Algorithm 1,
+        run one denoising step for the active batch, retire completions.
+        The caller owns the clock and should advance it by ``events.dt``."""
+        with jax.profiler.TraceAnnotation("ps.tick"):
+            return self._tick(now)
+
+    def _tick(self, now: float) -> TickEvents:
+        ev = TickEvents(now=now)
+        m = self.metrics
+
+        with jax.profiler.TraceAnnotation("ps.schedule"):
+            admitted, dropped = self.scheduler.schedule(self.wait,
+                                                        self.active, now)
+        for r in dropped:
+            self.wait.remove(r)
+            r.state = "dropped"
+            m.dropped += 1
+            ev.dropped.append(r)
+        for r in admitted:
+            self.wait.remove(r)
+            r.state = "active"
+            self._prepare(r)
+            self.active.append(r)
+            ev.admitted.append(r)
+        if not self.active:
+            return ev
+
+        # one denoising step for the whole mixed-resolution batch
+        step_pred = self._predict_step_latency(self.active)
+        comp = tuple(self._bucket(c) for c in self._counts(self.active))
+        is_cold = comp not in self._seen_shapes
+        self._seen_shapes.add(comp)
+        # batch locality features for the cache calibrator, captured before
+        # steps_done advances: concentration of the *bucketed* composition
+        # (what executes, dummy padding included)
+        conc = step_frac = 0.0
+        if self.cfg.use_cache:
+            conc = resolution_concentration(comp, self.patches_per_res)
+            step_frac = float(np.mean([r.steps_done / max(r.total_steps, 1)
+                                       for r in self.active]))
+        savings, ev.dt = self._timed_step(self.active)
+        if savings:
+            # measured tensor-path reuse: also feed the hit-model calibrator
+            m.compute_savings.append(float(np.mean(savings)))
+            m.cache_samples.append((conc, step_frac, float(np.mean(savings))))
+        ev.stepped = True
+        m.step_latencies.append(ev.dt)
+        m.step_predicted.append(step_pred)
+        end = ev.end
+
+        # straggler mitigation: a step far over prediction triggers
+        # re-estimation; newly hopeless actives are dropped at once.
+        # Cold (first-compile) compositions are exempt.
+        if (not is_cold
+                and ev.dt > self.cfg.straggler_factor * max(step_pred, 1e-9)):
+            for r in list(self.active):
+                if end + ev.dt * r.remaining_steps > r.slo:
+                    self.active.remove(r)
+                    r.state = "dropped"
+                    m.dropped += 1
+                    ev.dropped.append(r)
+
+        # completions
+        for r in list(self.active):
+            if r.steps_done >= r.total_steps:
+                self.active.remove(r)
+                self._postprocess(r)
+                r.state = "done"
+                r.finish = end
+                m.completed += 1
+                m.latencies.append(end - r.arrival)
+                if end <= r.slo:
+                    m.slo_met += 1
+                ev.completed.append(r)
+        return ev
+
+
+class PatchedServeEngine(ServingLoop):
+    """Runs each step's denoising on the device and times it on the wall
+    clock; ``drain`` and ``run`` advance by the wall clock too."""
+
+    def __init__(self, model_cfg: dm.DiffusionConfig, params,
+                 engine_cfg: EngineConfig,
+                 standalone_latency: Dict[Tuple[int, int], float],
+                 resolutions: Sequence[Tuple[int, int]]):
+        if engine_cfg.clock != "real":
+            raise ValueError("PatchedServeEngine runs on the wall clock: "
+                             f"clock must be 'real', got {engine_cfg.clock!r}")
+        super().__init__(engine_cfg, standalone_latency, resolutions)
+        self.mcfg = model_cfg
+        self.params = params
+        self.vae = vae_mod.init_vae(jax.random.PRNGKey(7),
+                                    model_cfg.latent_channels)
+        self.rng = np.random.default_rng(engine_cfg.seed)
+        self.caches: Dict[str, cache_mod.PatchCache] = {}
+        self.predictor = ThresholdPredictor(engine_cfg.cache_tau)
+        self._uid_base: Dict[int, int] = {}   # rid -> uid namespace
+        self.outputs: Dict[int, np.ndarray] = {}
 
     # ---------------- calibration (paper §6.1 Throughput Analyzer) ----------
 
@@ -248,8 +373,6 @@ class PatchedServeEngine:
 
     def _prepare(self, req: Request) -> None:
         self._uid_base[req.rid] = req.rid * (1 << 20)
-        if self.cfg.clock == "sim" and self.cfg.sim_synthetic:
-            return
         if req.latent is None:
             # fresh request; a checkpoint-resumed one arrives with its
             # snapshotted latent and must NOT be re-noised — it continues
@@ -263,8 +386,6 @@ class PatchedServeEngine:
                                              self.mcfg.d_text)
 
     def _postprocess(self, req: Request) -> None:
-        if self.cfg.clock == "sim" and self.cfg.sim_synthetic:
-            return
         with jax.profiler.TraceAnnotation("ps.decode"):
             img = vae_mod.vae_decode(self.vae, req.latent[None])[0]
             self.outputs[req.rid] = self._host_sync(np.asarray, img)
@@ -327,128 +448,7 @@ class PatchedServeEngine:
 
         return hook, savings
 
-    # ---------------- steppable API ----------------
-
-    def submit(self, req: Request) -> None:
-        """Enqueue an arrived request; it is considered by Algorithm 1 on the
-        next ``tick``."""
-        self.wait.append(req)
-
-    @property
-    def has_work(self) -> bool:
-        return bool(self.wait or self.active)
-
-    @property
-    def queue_depth(self) -> int:
-        return len(self.wait) + len(self.active)
-
-    def backlog_estimate(self) -> float:
-        """Predicted seconds until this engine drains everything it holds,
-        assuming all of it batches together (upper-bounds composition; the
-        router only needs a comparable load signal, not an exact forecast)."""
-        reqs = self.active + self.wait
-        if not reqs:
-            return 0.0
-        step = self._predict_step_latency(reqs)
-        return step * max(r.remaining_steps for r in reqs)
-
-    def reset_metrics(self) -> None:
-        """Fresh Metrics; keeps compile/shape caches so warm engines stay
-        warm across runs."""
-        self.metrics = Metrics()
-
-    def tick(self, now: float) -> TickEvents:
-        """One engine iteration at clock time ``now``: admit via Algorithm 1,
-        run one denoising step for the active batch, retire completions.
-        The caller owns the clock and should advance it by ``events.dt``."""
-        with jax.profiler.TraceAnnotation("ps.tick"):
-            return self._tick(now)
-
-    def _tick(self, now: float) -> TickEvents:
-        ev = TickEvents(now=now)
-        m = self.metrics
-
-        with jax.profiler.TraceAnnotation("ps.schedule"):
-            admitted, dropped = self.scheduler.schedule(self.wait,
-                                                        self.active, now)
-        for r in dropped:
-            self.wait.remove(r)
-            r.state = "dropped"
-            m.dropped += 1
-            ev.dropped.append(r)
-        for r in admitted:
-            self.wait.remove(r)
-            r.state = "active"
-            self._prepare(r)
-            self.active.append(r)
-            ev.admitted.append(r)
-        if not self.active:
-            return ev
-
-        # one denoising step for the whole mixed-resolution batch
-        step_pred = self._predict_step_latency(self.active)
-        comp = tuple(self._bucket(c) for c in self._counts(self.active))
-        is_cold = comp not in self._seen_shapes
-        self._seen_shapes.add(comp)
-        # batch locality features, captured before steps_done advances —
-        # consumed by the real-path cache calibrator and the cache-aware sim
-        # surrogate's hit-rate metric; skipped when neither is active.
-        # A surrogate advertises cache-awareness by exposing a truthy
-        # ``cache`` attribute alongside ``modeled_hit_rate``.
-        lm = getattr(self, "latency_model", None)
-        mh = getattr(lm, "modeled_hit_rate", None) \
-            if self.cfg.clock == "sim" and getattr(lm, "cache", None) \
-            is not None else None
-        conc = step_frac = 0.0
-        if mh is not None or (self.cfg.use_cache and self.cfg.clock == "real"):
-            # concentration of the *bucketed* composition (what executes,
-            # dummy padding included) — matches what a cache-aware
-            # surrogate's predict_batch prices, so the reported hit rate
-            # agrees with the one that shaped the latency
-            conc = resolution_concentration(comp, self.patches_per_res)
-            step_frac = float(np.mean([r.steps_done / max(r.total_steps, 1)
-                                       for r in self.active]))
-        savings, step_real = self._timed_step(self.active)
-        if savings:
-            # measured tensor-path reuse: also feed the hit-model calibrator
-            m.compute_savings.append(float(np.mean(savings)))
-            m.cache_samples.append((conc, step_frac, float(np.mean(savings))))
-        elif mh is not None:
-            # sim clock: a cache-aware surrogate reports its *modeled* hit
-            # rate so fleet metrics can aggregate locality per replica
-            m.compute_savings.append(mh(conc, step_frac))
-
-        ev.dt = step_real if self.cfg.clock == "real" else step_pred
-        ev.stepped = True
-        m.step_latencies.append(ev.dt)
-        m.step_predicted.append(step_pred)
-        end = ev.end
-
-        # straggler mitigation: a step far over prediction triggers
-        # re-estimation; newly hopeless actives are dropped at once.
-        # Cold (first-compile) compositions are exempt.
-        if (self.cfg.clock == "real" and not is_cold
-                and step_real > self.cfg.straggler_factor * max(step_pred, 1e-9)):
-            for r in list(self.active):
-                if end + step_real * r.remaining_steps > r.slo:
-                    self.active.remove(r)
-                    r.state = "dropped"
-                    m.dropped += 1
-                    ev.dropped.append(r)
-
-        # completions
-        for r in list(self.active):
-            if r.steps_done >= r.total_steps:
-                self.active.remove(r)
-                self._postprocess(r)
-                r.state = "done"
-                r.finish = end
-                m.completed += 1
-                m.latencies.append(end - r.arrival)
-                if end <= r.slo:
-                    m.slo_met += 1
-                ev.completed.append(r)
-        return ev
+    # ---------------- clock ----------------
 
     def drain(self, now: float = 0.0,
               max_wall: float = 1e9) -> Tuple[float, List[TickEvents]]:
@@ -460,55 +460,33 @@ class PatchedServeEngine:
         while self.has_work:
             ev = self.tick(now)
             events.append(ev)
-            if self.cfg.clock == "sim":
-                now += ev.dt
-            else:
-                now = start_now + (time.perf_counter() - t0)
+            now = start_now + (time.perf_counter() - t0)
             if not (ev.stepped or ev.admitted or ev.dropped):
                 break                      # starved: nothing admissible
             if time.perf_counter() - t0 > max_wall:
                 break
         return now, events
 
-    # ---------------- main loop (thin wrapper over the steppable API) ------
-
     def run(self, workload: List[Request], max_wall: float = 1e9) -> Metrics:
-        pending = sorted(workload, key=lambda r: r.arrival)
-        # each run() is self-contained: discard anything a previous
-        # max_wall-truncated run (or external submit/tick use) left queued
-        self.wait.clear()
-        self.active.clear()
-        self.reset_metrics()
+        """Serve ``workload`` with arrivals released on the wall clock."""
+        pending = self._start_run(workload)
         m = self.metrics
-        now = 0.0
         t_start = time.perf_counter()
-
-        def clock() -> float:
-            return (time.perf_counter() - t_start
-                    if self.cfg.clock == "real" else now)
-
         while pending or self.has_work:
-            t = clock()
-            if (self.cfg.clock == "sim" and not self.has_work and pending):
-                now = max(now, pending[0].arrival)
-                t = now
+            t = time.perf_counter() - t_start
             while pending and pending[0].arrival <= t:
                 self.submit(pending.pop(0))
             if not self.has_work:
-                if self.cfg.clock == "real" and pending:
+                if pending:
                     time.sleep(max(pending[0].arrival - t, 0))
                 continue
-
-            ev = self.tick(t)
-            if self.cfg.clock == "sim":
-                if ev.stepped:
-                    now = ev.end
-                elif not self.active and pending:
-                    now = pending[0].arrival
+            self.tick(t)
             if time.perf_counter() - t_start > max_wall:
                 break
-        m.span = clock()
+        m.span = time.perf_counter() - t_start
         return m
+
+    # ---------------- device step ----------------
 
     DUMMY_BASE = 1 << 40
 
@@ -528,6 +506,7 @@ class PatchedServeEngine:
             pool[key] = r
         return r
 
+
     def _timed_step(self, reqs: List[Request]) -> Tuple[List[float], float]:
         """One denoising step and its wall time. Dispatch is asynchronous,
         so the clock stops only once the new latents exist on the device."""
@@ -538,11 +517,6 @@ class PatchedServeEngine:
         return savings, time.perf_counter() - t0
 
     def _denoise_step(self, active: List[Request]) -> List[float]:
-        if self.cfg.clock == "sim" and self.cfg.sim_synthetic:
-            # synthetic sim: no tensors exist; a step is pure accounting
-            for r in active:
-                r.steps_done += 1
-            return []
         with jax.profiler.TraceAnnotation("ps.csp_split"):
             # bucket-pad per resolution so XLA sees a bounded shape lattice
             padded = list(active)
@@ -560,20 +534,16 @@ class PatchedServeEngine:
 
         savings: List[float] = []
         hook = None
-        if self.cfg.use_cache and self.cfg.clock == "real":
+        if self.cfg.use_cache:
             frac = float(np.mean([r.steps_done for r in active])) / total_steps
             hook, savings = self._block_hook(csp, frac)
 
-        if self.cfg.clock == "sim":
-            # virtual clock: skip device math, only cache bookkeeping savings
-            new_patches = patches
-        else:
-            traces = dm.segment_traces()
-            with jax.profiler.TraceAnnotation("ps.sampler"):
-                new_patches = sampler_mod.sampler_step(
-                    self.mcfg, self.params, csp, patches, step_req,
-                    total_steps, text, block_hook=hook)
-            self.metrics.block_traces += dm.segment_traces() - traces
+        traces = dm.segment_traces()
+        with jax.profiler.TraceAnnotation("ps.sampler"):
+            new_patches = sampler_mod.sampler_step(
+                self.mcfg, self.params, csp, patches, step_req,
+                total_steps, text, block_hook=hook)
+        self.metrics.block_traces += dm.segment_traces() - traces
         with jax.profiler.TraceAnnotation("ps.csp_merge"):
             outs = merge_by_request(csp, new_patches)
             for r in active:            # dummies' outputs are discarded

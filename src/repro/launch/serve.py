@@ -1,5 +1,5 @@
-"""Serving launcher: run the PatchedServe engine on a real or simulated
-workload. ``python -m repro.launch.serve --qps 1.0 --duration 5 --cache``."""
+"""Serving launcher: run the PatchedServe engine on a Poisson workload of a
+tiny model. ``python -m repro.launch.serve --qps 1.0 --duration 5 --cache``."""
 from __future__ import annotations
 
 import argparse
@@ -23,7 +23,6 @@ def main():
     ap.add_argument("--slo-scale", type=float, default=5.0)
     ap.add_argument("--cache", action="store_true")
     ap.add_argument("--policy", default="slo", choices=["slo", "fcfs"])
-    ap.add_argument("--clock", default="real", choices=["real", "sim"])
     args = ap.parse_args()
     enable_compile_cache()
 
@@ -32,19 +31,12 @@ def main():
                               d_text=16, n_text=4, use_kernels=False)
     params = dm.init_diffusion(mcfg, jax.random.PRNGKey(0))
     resolutions = [(16, 16), (24, 24), (32, 32)]
-    ecfg = EngineConfig(clock=args.clock, use_cache=args.cache,
+    ecfg = EngineConfig(use_cache=args.cache,
                         scheduler=SchedulerConfig(policy=args.policy))
     eng = PatchedServeEngine(mcfg, params, ecfg,
                              dict.fromkeys(map(tuple, resolutions), 1.0),
                              resolutions)
-    if args.clock == "real":
-        eng.calibrate(total_steps_hint=args.steps)
-    else:
-        from repro.core.latency_model import analytic_step_latency
-        for res, ppr in zip(eng.resolutions, eng.patches_per_res):
-            eng.sa[res] = analytic_step_latency(
-                [1 if r == res else 0 for r in eng.resolutions],
-                eng.patches_per_res) * args.steps
+    eng.calibrate(total_steps_hint=args.steps)
     wl = poisson_workload(args.qps, args.duration, resolutions,
                           args.slo_scale, eng.sa, steps=args.steps)
     m = eng.run(wl)

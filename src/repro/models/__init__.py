@@ -1,1 +1,4 @@
-from repro.models import attention, lm, layers, mamba, moe  # noqa: F401
+"""Model definitions: the diffusion backbones the engine serves
+(``diffusion``, ``sampler``, ``vae``) and an LM training stack (``lm``,
+``attention``, ``mamba``, ``moe``, ``flash``) that the serving path does not
+use. Import each submodule by name."""

@@ -8,6 +8,7 @@ injection + recovery), and the fault-tolerance layer (partial-progress
 checkpointing, correlated zone outages, fault-domain-aware dispatch)."""
 import json
 
+import jax
 import numpy as np
 import pytest
 
@@ -26,6 +27,8 @@ from repro.core.latency_model import (CacheHitModel, fit_cache_hit_model,
                                       patch_aware_step_latency,
                                       resolution_concentration)
 from repro.core.requests import Request
+from repro.core.serving import EngineConfig, PatchedServeEngine
+from repro.models import diffusion as dm
 
 SKEW = (0.2, 0.2, 0.6)          # mostly-High mix: stresses routing
 MIX_A = (0.6, 0.3, 0.1)         # drift scenario: Low-heavy ...
@@ -1173,13 +1176,21 @@ def test_zone_kills_leave_crash_budget_intact():
 
 
 def test_checkpoint_restores_latent_on_tensor_path():
-    """On a non-synthetic (tensor) sim engine the snapshot must carry the
-    latent: a resumed orphan continues mid-denoise from the snapshotted
-    state instead of skipping its first k steps on fresh noise."""
-    factory = sim_engine_factory(DEFAULT_RES, synthetic=False)
-    rep = Replica(0, factory(DEFAULT_RES),
-                  checkpoint=CheckpointConfig(every_k_steps=2))
-    req = Request(rid=0, resolution=DEFAULT_RES[0], arrival=0.0, slo=1e9,
+    """On a device engine the snapshot must carry the latent: a resumed
+    orphan continues mid-denoise from the snapshotted state instead of
+    skipping its first k steps on fresh noise."""
+    mcfg = dm.DiffusionConfig(kind="unet", width=16, levels=2,
+                              blocks_per_level=1, n_heads=2, groups=4,
+                              d_text=8, n_text=2, use_kernels=False)
+    params = dm.init_diffusion(mcfg, jax.random.PRNGKey(0))
+    res = [DEFAULT_RES[0]]
+
+    def engine():
+        return PatchedServeEngine(mcfg, params, EngineConfig(),
+                                  {res[0]: 1.0}, res)
+
+    rep = Replica(0, engine(), checkpoint=CheckpointConfig(every_k_steps=2))
+    req = Request(rid=0, resolution=res[0], arrival=0.0, slo=1e9,
                   total_steps=6)
     rep.submit(req)
     now = 0.0
@@ -1195,16 +1206,23 @@ def test_checkpoint_restores_latent_on_tensor_path():
     assert orphan.latent is snap_latent
     # a second replica must serve only the remaining steps, without
     # re-noising the restored latent (engine _prepare keeps it)
-    rep2 = Replica(1, factory(DEFAULT_RES),
-                   checkpoint=CheckpointConfig(every_k_steps=2))
+    eng2 = engine()
+    prepared = []
+
+    def prepare(r, _inner=eng2._prepare):
+        _inner(r)
+        prepared.append(r.latent)
+
+    eng2._prepare = prepare
+    rep2 = Replica(1, eng2, checkpoint=CheckpointConfig(every_k_steps=2))
     rep2.submit(orphan)
     ev = rep2.tick(now + 1.0)
-    # admitted AND already stepped once in the same tick; _prepare kept the
-    # restored latent (on the sim tensor path a step passes patches through
-    # unchanged, so re-noising — fresh rng draw — would show as a mismatch)
+    # admitted AND already stepped once in the same tick, from the restored
+    # latent (a re-noised one would be a fresh rng draw)
     assert ev.admitted and ev.stepped
+    assert len(prepared) == 1 and prepared[0] is snap_latent
     assert orphan.steps_done == 5
-    assert np.allclose(np.asarray(orphan.latent), np.asarray(snap_latent))
+    assert not np.allclose(np.asarray(orphan.latent), np.asarray(snap_latent))
     steps, t = 1, rep2.next_free
     while rep2.has_work and steps < 10:
         if rep2.tick(t).stepped:

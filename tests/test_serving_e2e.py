@@ -1,9 +1,11 @@
-"""End-to-end serving: real-clock tiny run, sim-clock scheduler properties,
+"""End-to-end serving: real-clock tiny run, scheduler properties on the
+accounting engine's virtual clock,
 quality preservation under patched execution + caching off."""
 import jax
 import numpy as np
 import pytest
 
+from repro.cluster import SimEngine
 from repro.core.latency_model import analytic_step_latency
 from repro.core.requests import poisson_workload
 from repro.core.scheduler import SchedulerConfig
@@ -21,11 +23,9 @@ def tiny_model():
 
 
 def sim_engine(policy="slo", use_cache=False, seed=0):
-    cfg, params = tiny_model()
-    ecfg = EngineConfig(clock="sim", use_cache=use_cache,
+    ecfg = EngineConfig(use_cache=use_cache,
                         scheduler=SchedulerConfig(policy=policy))
-    eng = PatchedServeEngine(cfg, params, ecfg,
-                             dict.fromkeys(map(tuple, RES), 1.0), RES)
+    eng = SimEngine(ecfg, dict.fromkeys(map(tuple, RES), 1.0), RES)
     for res in eng.resolutions:
         eng.sa[res] = analytic_step_latency(
             [1 if r == res else 0 for r in eng.resolutions],
